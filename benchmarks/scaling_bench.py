@@ -54,18 +54,20 @@ edges — locality alone would wave the regression through (restream did
 exactly this, max_norm_load ~6 at 8 shards, before per-shard capacity
 rationing fixed it).
 
-Device count must be pinned before the backend initializes, so each count
-runs in its own **worker subprocess** launched with
-``XLA_FLAGS=--xla_force_host_platform_device_count=N``; the parent process
-orchestrates, merges the workers' JSON, and applies the quality gate (the CI
-regression check: exit nonzero when any sharded quality ratio drops below
-``--quality-gate``, default 0.97).
+On the CPU backend the device count must be pinned before the backend
+initializes, so each count runs in its own **worker subprocess** launched
+with ``XLA_FLAGS=--xla_force_host_platform_device_count=N``. On an
+accelerator a chip belongs to one process, so each count runs in-process
+on ``make_blocks_mesh(N)``, a sub-mesh of the visible devices, and counts
+above the visible devices are skipped. The parent orchestrates, merges the
+workers' JSON, and applies the quality gate (the CI regression check: exit
+nonzero when any sharded quality ratio drops below ``--quality-gate``,
+default 0.97).
 
-On a CPU container the forced host devices share the machine's physical
-cores (this box has very few), so the recorded wall-clock speedups are
-bounded by ``cpu_count``, not by the schedule — the provenance stamp records
-both so the trajectory stays comparable. On a real 8-device TPU slice the
-same harness measures true scaling.
+On the CPU backend the forced host devices share the machine's physical
+cores, so the recorded wall-clock speedups are bounded by ``cpu_count``,
+not by the schedule — the provenance stamp records both so the trajectory
+stays comparable. On a TPU host the same harness runs on the chips.
 
   PYTHONPATH=src python benchmarks/scaling_bench.py            # full
   PYTHONPATH=src python benchmarks/scaling_bench.py --quick    # CI smoke
@@ -404,7 +406,15 @@ def _worker(args) -> dict:
 _MARK = "SCALING_WORKER_JSON:"
 
 
-def _spawn_worker(args, devices: int, quality: bool) -> dict:
+def _run_worker(args, devices: int, quality: bool) -> dict:
+    """One device count's measurements: a forced-host-device subprocess on
+    the CPU backend, in-process on a sub-mesh of the visible devices
+    elsewhere (a parent holding the chip would starve a child)."""
+    import jax
+
+    if jax.default_backend() != "cpu":
+        return _worker(argparse.Namespace(**vars(args), devices=devices,
+                                          quality=quality, halo=quality))
     env = dict(os.environ)
     flags = [f for f in env.get("XLA_FLAGS", "").split()
              if not f.startswith("--xla_force_host_platform_device_count")]
@@ -495,6 +505,12 @@ def run(*, quick: bool = False, out: str = "BENCH_scaling.json",
         "async": [],
     }
 
+    import jax
+
+    if jax.default_backend() != "cpu":
+        device_counts = tuple(n for n in device_counts
+                              if n <= jax.device_count())
+        results["meta"]["device_counts"] = list(device_counts)
     base = {}   # (dataset, algo) -> 1-device sharded steps/s
     print(f"{'devices':>7s} {'dataset':8s} {'algo':9s} {'supersteps/s':>12s} "
           f"{'edges/s':>12s} {'speedup':>8s}")
@@ -502,7 +518,7 @@ def run(*, quick: bool = False, out: str = "BENCH_scaling.json",
         # quality needs the Jacobi merge actually split across shards, so it
         # is measured in the max-device worker (and trivially at 1 device,
         # where sharded == sequential bit-exactly)
-        worker = _spawn_worker(args, devices, quality=devices == max(device_counts))
+        worker = _run_worker(args, devices, quality=devices == max(device_counts))
         for row in worker["rows"]:
             row["devices"] = devices
             bkey = (row["dataset"], row["algo"])

@@ -24,7 +24,7 @@ Five hard gates (process exits nonzero on failure — the CI regression check):
     while spending at most ``VCYCLE_STEPS_GATE`` (0.5) of flat's
     supersteps at the fine level (docs/multilevel.md).
 
-On this CPU container the Pallas paths execute in interpret mode, so their
+On the CPU backend the Pallas paths execute in interpret mode, so their
 wall-clock is a harness/correctness sanity check, not TPU perf (see
 kernel_bench.py); the numbers that matter for the trajectory are the XLA-path
 throughputs and the fused-vs-two-call ratio measured under the same mode.

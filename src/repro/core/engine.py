@@ -64,7 +64,6 @@ from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import obs
@@ -746,11 +745,11 @@ def _sharded_superstep(algo, cfg, mesh, layout, graph, cap, donated, kept):
                  if f not in algo.replicated_fields}
     out_specs["score"] = P()
     body = partial(_BODIES[algo.kind], algo, cfg, layout, AXIS)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         body, mesh=mesh,
         in_specs=({k: _GRAPH_SPECS[k] for k in graph}, P(), state_specs, P()),
         out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )
     out = sharded(graph, cap, state, step)
     return _finish(algo, layout, state, out, step)
@@ -786,12 +785,12 @@ def _async_sharded_superstep(algo, cfg, mesh, layout, split, refresh,
     tail_specs = {f: P(AXIS) for f in algo.vertex_fields}
     body = partial(_async_chunk_superstep, algo, cfg, layout, split, refresh,
                    AXIS)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         body, mesh=mesh,
         in_specs=({k: _GRAPH_SPECS[k] for k in graph}, P(), state_specs,
                   cache_specs, P()),
         out_specs=(out_specs, tail_specs),
-        check_rep=False,
+        check_vma=False,
     )
     out, new_cache = sharded(graph, cap, state, cache, step)
     return _finish(algo, layout, state, out, step), new_cache

@@ -9,22 +9,25 @@ same blocked edge slab (DESIGN.md §3, Section IV-D steps 3 and 5):
     depends on whether the neighbor's latest lambda agrees with v's selected
     action and on slot feasibility (p_mig > 0).
 
-Run separately (`edge_histogram` twice) each histogram re-reads the slab from
-HBM, re-builds the [Ec, Bv] row-indicator matrix R, and re-launches the grid.
-This kernel computes **both in a single pass**: one R shared across two MXU
-matmuls (R^T @ L_score and R^T @ L_w), with the neighbor-label gathers, the
-agreement/feasibility masking, and the padding kill done in-kernel, so the
-two [Bv, k] accumulators stay VMEM-resident across all edge chunks of a
-block (grid minor dimension = edge chunks). Versus two independent kernel
-launches this halves slab HBM traffic and indicator construction; versus the
-XLA path it eliminates the double scatter-add.
+Run separately (`edge_histogram` twice) each histogram re-builds the
+[Bv, Ec] row-indicator matrix R and re-launches the grid. This kernel
+computes **both in a single pass**: one R shared across two MXU matmuls
+(L_score @ R^T and L_w @ R^T), so the two [k, Bv] accumulators stay
+VMEM-resident across all edge chunks of a block (grid minor dimension =
+edge chunks).
+
+The data-dependent gathers (neighbor label and lambda, the vertex's
+action, slot feasibility) run in the XLA wrapper: the TPU kernel compiler
+only gathers along 2-D tiles, and a VMEM-resident [n_pad] label vector
+would cap the graph size besides. The wrapper turns each edge into two
+(slot, value) pairs streamed as lane-major `[nb, 1, e_max]` slabs; the
+kernel is left with the indicator construction and the two matmuls.
 
 Slot-selection for the two `weight_mode`s (the eq.-13 ambiguity, DESIGN.md
 §10):
 
   * ``neighbor_lambda`` — the weight histogram's slot is lambda(u), known
-    per edge in-kernel, so L_w is a full [Ec, k] indicator and the kernel
-    returns the finished w_raw.
+    per edge, so the kernel returns the finished w_raw.
   * ``self_lambda`` — the slot is lambda(v) = argmax score(v, :), which only
     exists *after* all edge chunks are reduced. But every edge of row v then
     lands in the same slot, so the row's contribution factors into two
@@ -33,16 +36,15 @@ Slot-selection for the two `weight_mode`s (the eq.-13 ambiguity, DESIGN.md
         A[v] = sum_e agree(e) * w(e)          (agreement mass)
         N[v] = #{e : !agree(e), non-padding}  (disagreement count)
 
-    The kernel accumulates A into column 0 and N into column 1 of the
-    second output; the caller scatters ``A + feasible(lambda(v)) * N`` into
-    the one-hot lambda(v) slot. The fusion is exact: every kernel input
+    An edge adds to exactly one of them, so the wrapper routes it to slot 0
+    (A) or slot 1 (N); the caller scatters ``A + feasible(lambda(v)) * N``
+    into the one-hot lambda(v) slot. The fusion is exact: every input
     (labels, lam, action, p_mig) is available before the edge phase.
 
-VMEM budget: the label/lambda gathers keep the full [n_pad] int32 vectors
-VMEM-resident (8 bytes/vertex total). That holds to n_pad ~ 1M/core; beyond
-that the slab's dst ids must be pre-translated host-side into per-block
-label chunks (the streaming layer's dirty-block machinery already tracks the
-needed locality) — see kernels/README.md.
+VMEM bound: each grid cell holds the [block_v, edge_chunk] f32 indicator,
+so ``block_v * edge_chunk`` is capped at `MAX_INDICATOR_ELEMS`; a larger
+vertex block raises ValueError up front rather than being left to the TPU
+compiler. See kernels/README.md.
 """
 from __future__ import annotations
 
@@ -54,53 +56,58 @@ from jax.experimental import pallas as pl
 
 _WEIGHT_MODES = ("self_lambda", "neighbor_lambda")
 
+# block_v * edge_chunk elements of the per-cell row indicator, ~16 B each
+# with its iota: 2048 x 256 compiles for a v5e, 4096 x 256 needs 16.17 MiB
+# of the 16 MiB default scoped VMEM and is refused (tests/test_tpu_compile.py
+# compiles the bound)
+MAX_INDICATOR_ELEMS = 2048 * 256
 
-def _kernel(dst_ref, row_ref, w_ref, lbl_ref, lam_ref, act_ref, feas_ref,
-            hist_ref, wacc_ref, *, block_v: int, k: int, weight_mode: str):
+
+def _kernel(row_ref, sa_ref, va_ref, sb_ref, vb_ref, ha_ref, hb_ref, *,
+            block_v: int, k: int):
     """One (vertex-block, edge-chunk) grid cell; accumulates both outputs."""
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        hist_ref[...] = jnp.zeros_like(hist_ref)
-        wacc_ref[...] = jnp.zeros_like(wacc_ref)
+        ha_ref[...] = jnp.zeros_like(ha_ref)
+        hb_ref[...] = jnp.zeros_like(hb_ref)
 
-    dst = dst_ref[0]            # [Ec] int32 global neighbor id
-    row = row_ref[0]            # [Ec] int32 local row per edge
-    w = w_ref[0]                # [Ec] f32   eq.-4 weight (0 for padding)
-    labels = lbl_ref[...]       # [n_pad] int32 freshest labels (async)
-    lam = lam_ref[...]          # [n_pad] int32 latest argmax labels
-    action = act_ref[0]         # [Bv] int32 LA-selected action psi(v)
-    feas = feas_ref[0]          # [k] f32 1.0 where p_mig(l) > 0
-    ec = dst.shape[0]
+    row = row_ref[0]            # [1, Ec] int32 local row per edge
+    ec = row.shape[-1]
+    rows_iota = jax.lax.broadcasted_iota(jnp.int32, (block_v, ec), 0)
+    slot_iota = jax.lax.broadcasted_iota(jnp.int32, (k, ec), 0)
+    r_mat = (rows_iota == row).astype(jnp.float32)     # shared R, [Bv, Ec]
+    l_a = (slot_iota == sa_ref[0]).astype(jnp.float32) * va_ref[0]   # [k, Ec]
+    l_b = (slot_iota == sb_ref[0]).astype(jnp.float32) * vb_ref[0]
 
-    nbr_lbl = labels[dst]       # in-kernel gathers: one slab read serves both
-    lam_nbr = lam[dst]
-    live = (w > 0).astype(jnp.float32)          # padding kill
-    agree = action[row] == lam_nbr              # psi(v) == lambda(u)
+    dn = (((1,), (1,)), ((), ()))   # contract edges: [k,Ec] x [Bv,Ec] -> [k,Bv]
+    hp = jax.lax.Precision.HIGHEST
+    ha_ref[0] += jax.lax.dot_general(
+        l_a, r_mat, dimension_numbers=dn, precision=hp,
+        preferred_element_type=jnp.float32)
+    hb_ref[0] += jax.lax.dot_general(
+        l_b, r_mat, dimension_numbers=dn, precision=hp,
+        preferred_element_type=jnp.float32)
 
-    rows_iota = jax.lax.broadcasted_iota(jnp.int32, (ec, block_v), 1)
-    slot_iota = jax.lax.broadcasted_iota(jnp.int32, (ec, k), 1)
-    r_mat = (row[:, None] == rows_iota).astype(jnp.float32)      # shared R
-    l_score = (nbr_lbl[:, None] == slot_iota).astype(jnp.float32) * w[:, None]
 
+def _edge_slot_values(edge_dst, edge_rows, edge_vals, labels, lam, actions,
+                     feasible, weight_mode: str):
+    """Per-edge (slot, value) pairs of both histograms — the gathers the
+    kernel leaves to XLA. Returns ``(score_slot, score_val, w_slot,
+    w_val)``, each ``[nb, e_max]``."""
+    nbr_lbl = labels[edge_dst]
+    lam_nbr = lam[edge_dst]
+    live = (edge_vals > 0).astype(jnp.float32)           # padding kill
+    agree = jnp.take_along_axis(actions, edge_rows, axis=1) == lam_nbr
     if weight_mode == "neighbor_lambda":
-        # slot = lambda(u): full indicator, finished w_raw out of the kernel
-        val = jnp.where(agree, w, feas[lam_nbr]) * live
-        l_w = (lam_nbr[:, None] == slot_iota).astype(jnp.float32) * val[:, None]
-    else:  # self_lambda: per-row (A, N) factorization, see module docstring
-        a_col = jnp.where(agree, w, 0.0)[:, None]
-        n_col = jnp.where(agree, 0.0, live)[:, None]
-        l_w = jnp.where(slot_iota == 0, a_col,
-                        jnp.where(slot_iota == 1, n_col, 0.0))
-
-    dn = (((0,), (0,)), ((), ()))               # R^T @ L
-    hist_ref[0] += jax.lax.dot_general(
-        r_mat, l_score, dimension_numbers=dn,
-        preferred_element_type=jnp.float32)
-    wacc_ref[0] += jax.lax.dot_general(
-        r_mat, l_w, dimension_numbers=dn,
-        preferred_element_type=jnp.float32)
+        w_slot = lam_nbr
+        feas_nbr = jnp.take_along_axis(feasible, lam_nbr, axis=1)
+        w_val = jnp.where(agree, edge_vals, feas_nbr) * live
+    else:  # self_lambda: A -> slot 0, N -> slot 1 (module docstring)
+        w_slot = jnp.where(agree, 0, 1).astype(jnp.int32)
+        w_val = jnp.where(agree, edge_vals, live)
+    return nbr_lbl, edge_vals, w_slot, w_val
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -137,28 +144,27 @@ def fused_edge_phase_pallas(
     if e_max % edge_chunk != 0:
         # a floored chunk count would silently drop the slab tail
         raise ValueError(f"e_max={e_max} not a multiple of edge_chunk={edge_chunk}")
-    n_pad = labels.shape[0]
-    n_chunks = e_max // edge_chunk
+    if block_v * edge_chunk > MAX_INDICATOR_ELEMS:
+        raise ValueError(
+            f"fused edge phase cannot hold block_v={block_v} x "
+            f"edge_chunk={edge_chunk} ({block_v * edge_chunk} indicator "
+            f"elements > {MAX_INDICATOR_ELEMS}); use more, smaller vertex "
+            f"blocks (n_blocks) or hist_impl='jnp'")
 
-    grid = (nb, n_chunks)
-    out_shape = [jax.ShapeDtypeStruct((nb, block_v, k), jnp.float32)] * 2
-    return pl.pallas_call(
-        functools.partial(
-            _kernel, block_v=block_v, k=k, weight_mode=weight_mode),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, edge_chunk), lambda i, j: (i, j)),
-            pl.BlockSpec((1, edge_chunk), lambda i, j: (i, j)),
-            pl.BlockSpec((1, edge_chunk), lambda i, j: (i, j)),
-            pl.BlockSpec((n_pad,), lambda i, j: (0,)),
-            pl.BlockSpec((n_pad,), lambda i, j: (0,)),
-            pl.BlockSpec((1, block_v), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_v, k), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, block_v, k), lambda i, j: (i, 0, 0)),
-        ],
-        out_shape=out_shape,
+    slabs = _edge_slot_values(edge_dst, edge_rows, edge_vals, labels, lam,
+                             actions, feasible, weight_mode)
+    # lane-major [nb, 1, e_max]: the (1, edge_chunk) tail of every block is
+    # a full-extent sublane dim by a lane-aligned chunk
+    row, sa, va, sb, vb = (x.reshape(nb, 1, e_max)
+                           for x in (edge_rows, *slabs))
+    slab_spec = pl.BlockSpec((1, 1, edge_chunk), lambda i, j: (i, 0, j))
+    out_spec = pl.BlockSpec((1, k, block_v), lambda i, j: (i, 0, 0))
+    hist_t, wacc_t = pl.pallas_call(
+        functools.partial(_kernel, block_v=block_v, k=k),
+        grid=(nb, e_max // edge_chunk),
+        in_specs=[slab_spec] * 5,
+        out_specs=[out_spec, out_spec],
+        out_shape=[jax.ShapeDtypeStruct((nb, k, block_v), jnp.float32)] * 2,
         interpret=interpret,
-    )(edge_dst, edge_rows, edge_vals, labels, lam, actions, feasible)
+    )(row, sa, va, sb, vb)
+    return hist_t.transpose(0, 2, 1), wacc_t.transpose(0, 2, 1)

@@ -31,7 +31,9 @@ def _kernel(p_ref, w_ref, r_ref, ord_ref, out_ref, *,
     iota = jax.lax.broadcasted_iota(jnp.int32, (bv, k), 1)
 
     def pass_t(t, p):
-        i = jax.lax.dynamic_slice_in_dim(order, t, 1, axis=1)        # [Bv, 1]
+        # pass t's action per row; a masked lane sum, not a dynamic slice
+        # (which the TPU kernel compiler does not lower)
+        i = jnp.sum(jnp.where(iota == t, order, 0), axis=1, keepdims=True)
         mask = iota == i
         w_i = jnp.sum(jnp.where(mask, w, 0.0), axis=1, keepdims=True)
         # eq. (8): reward pass for action i

@@ -1,8 +1,10 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-Every op auto-selects ``interpret=True`` on CPU (this container) and the
-compiled TPU path elsewhere; the ``ref.py`` oracles pin the semantics in
-tests/test_kernels.py. Call sites in the model zoo and the partitioner
+Every op auto-selects ``interpret=True`` on a CPU backend (the test
+suite's ``JAX_PLATFORMS=cpu``) and the compiled TPU path elsewhere; the
+``ref.py`` oracles pin the semantics in tests/test_kernels.py, and
+tests/test_tpu_compile.py compiles the partitioner's kernels for a
+described v5e. Call sites in the model zoo and the partitioner
 select implementations via config flags ("jnp" | "pallas") so the
 dry-run can lower the pure-XLA path while TPU deployments take the
 kernels.
@@ -57,6 +59,8 @@ def fused_edge_phase(edge_dst, edge_rows, edge_vals, labels, lam, actions,
     slab divides (the `block_edges` invariant) or one whole-slab chunk for
     sub-256 slabs; a larger non-divisible slab raises in the kernel wrapper
     rather than silently building an oversized [e_max, block_v] indicator.
+    A ``block_v`` whose row indicator does not fit VMEM raises ValueError
+    (`edge_phase.MAX_INDICATOR_ELEMS`); there is no silent jnp fallback.
     """
     e_max = edge_dst.shape[-1]
     if edge_chunk is None:

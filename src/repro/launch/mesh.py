@@ -8,18 +8,10 @@ from __future__ import annotations
 import jax
 
 
-def make_mesh_compat(shape, axes):
-    """`jax.make_mesh` across JAX versions.
-
-    `axis_types` / `jax.sharding.AxisType` only exist on newer JAX; older
-    versions (e.g. 0.4.x) default every axis to the same auto behavior, so
-    omitting the argument is semantically equivalent there.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def _auto_mesh(shape, axes):
+    """`jax.make_mesh` with every axis in the Auto sharding mode."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -27,12 +19,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     multi-pod:  (pod=2, data=16, model=16) = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """1-chip mesh with the production axis names (tests/smoke runs)."""
-    return make_mesh_compat((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 def make_blocks_mesh(n_shards: int | None = None):

@@ -22,6 +22,7 @@ from repro.core.registry import (
     get_algorithm,
 )
 from repro.graphs import load_dataset
+from repro.utils.compile_cache import configure_compile_cache
 
 
 def main(argv=None):
@@ -102,6 +103,7 @@ def main(argv=None):
                          " JSON) covering every run to PATH; inspect with "
                          "tools/trace_report.py or at https://ui.perfetto.dev")
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     tracer = None
     if args.trace:

@@ -113,14 +113,10 @@ class Tracer:
         self._last_compile_args: Dict[str, Dict[str, Any]] = {}
         self._xprof = xprof
         if xprof:
-            try:
-                import jax
-                from jax.profiler import TraceAnnotation
+            import jax
 
-                self._named_scope = jax.named_scope
-                self._trace_annotation = TraceAnnotation
-            except Exception:   # pragma: no cover - jax always present here
-                self._xprof = False
+            self._named_scope = jax.named_scope
+            self._trace_annotation = jax.profiler.TraceAnnotation
 
     # ------------------------------------------------------------------ #
     # clocks / event plumbing

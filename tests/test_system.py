@@ -51,8 +51,8 @@ def test_checkpoint_atomic_and_elastic_restore():
         like = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
         # restore with explicit shardings = the elastic re-shard path
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((1,), ("model",))
+        mesh = jax.make_mesh((1,), ("model",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         from jax.sharding import NamedSharding, PartitionSpec as P
         sh = jax.tree.map(lambda x: NamedSharding(mesh, P()), like)
         out = restore_checkpoint(td, 7, like, shardings=sh)
@@ -125,8 +125,6 @@ def test_ef_int8_quantization_properties():
                                atol=float(scale))
 
 
-@pytest.mark.skipif(not hasattr(jax, "set_mesh"),
-                    reason="activation sharding needs jax.set_mesh (newer JAX)")
 def test_moe_shardmap_matches_ref_on_4_devices():
     """The expert-parallel shard_map dispatch (separate process: needs
     xla_force_host_platform_device_count, which must NOT leak into this
@@ -212,8 +210,6 @@ def test_clip_by_global_norm_property(seed):
     assert new_norm <= 1.0 + 1e-5
 
 
-@pytest.mark.skipif(not hasattr(jax, "set_mesh"),
-                    reason="EP2D path needs jax.set_mesh (newer JAX)")
 def test_moe_ep2d_matches_ref_on_8_devices():
     """Cross-pod EP (experts over pod x model) — §Perf C3 path."""
     import subprocess
