@@ -226,23 +226,29 @@ def _revolver_chunk_rule(cfg: RevolverConfig, ctx: engine.ChunkContext,
     else:  # pure-XLA lowering stays importable without the kernel package
         fused_op, la_op = None, None
 
-    key, k_act, k_mig = jax.random.split(key, 3)
-    cur = jax.lax.dynamic_slice(labels, (ctx.v0,), (bv,))
+    # Every operation of the rule sits in one phase scope (`la-select`,
+    # `edge-phase`, `migrate`, `la-update`), so device profiles split the
+    # superstep's time by phase (docs/observability.md).
+    with obs.annotate("la-select"):
+        key, k_act, k_mig = jax.random.split(key, 3)
+        cur = jax.lax.dynamic_slice(labels, (ctx.v0,), (bv,))
 
-    # -- 1. LA action selection (roulette wheel) -----------------------------
-    logits = jnp.log(jnp.clip(probs, 1e-30, 1.0))
-    action = jax.random.categorical(k_act, logits, axis=-1).astype(jnp.int32)
-    action = jnp.where(ctx.vmask, action, cur)
+        # -- 1. LA action selection (roulette wheel) -------------------------
+        logits = jnp.log(jnp.clip(probs, 1e-30, 1.0))
+        action = jax.random.categorical(k_act, logits,
+                                        axis=-1).astype(jnp.int32)
+        action = jnp.where(ctx.vmask, action, cur)
 
-    # -- 2. migration probability per partition ------------------------------
-    wants = (action != cur) & ctx.vmask
-    demand = jnp.zeros((k,), jnp.float32).at[action].add(ctx.deg * wants)  # m(l)
-    remaining = cap - loads                                                # r(l)
-    p_mig = jnp.where(
-        demand > 0,
-        jnp.clip(remaining / jnp.maximum(demand, 1e-9), 0.0, 1.0),
-        1.0,
-    )
+        # -- 2. migration probability per partition --------------------------
+        wants = (action != cur) & ctx.vmask
+        demand = jnp.zeros((k,), jnp.float32).at[action].add(
+            ctx.deg * wants)                                           # m(l)
+        remaining = cap - loads                                        # r(l)
+        p_mig = jnp.where(
+            demand > 0,
+            jnp.clip(remaining / jnp.maximum(demand, 1e-9), 0.0, 1.0),
+            1.0,
+        )
 
     # -- 3. + 5. edge phase: LP-score histogram + eq.-13 accumulation --------
     # Both histograms read the same edge slab. Every input they need
@@ -265,19 +271,20 @@ def _revolver_chunk_rule(cfg: RevolverConfig, ctx: engine.ChunkContext,
             hist = edge_histogram_jnp(ctx.e_row, nbr_labels, ctx.e_w, bv, k)
             w_acc = None
 
-    scores = revolver_scores(hist, ctx.inv_wsum, loads, cap)
-    lam_chunk = jnp.argmax(scores, axis=-1).astype(jnp.int32)
-    best = jnp.max(scores, axis=-1)
-    score = jnp.sum(jnp.where(ctx.vmask, best, 0.0))
+        scores = revolver_scores(hist, ctx.inv_wsum, loads, cap)
+        lam_chunk = jnp.argmax(scores, axis=-1).astype(jnp.int32)
+        best = jnp.max(scores, axis=-1)
+        score = jnp.sum(jnp.where(ctx.vmask, best, 0.0))
 
-    # -- 4. gated migration ---------------------------------------------------
-    u = jax.random.uniform(k_mig, (bv,))
-    migrate = wants & (u < p_mig[action])
-    new_lbl = jnp.where(migrate, action, cur)
+    with obs.annotate("migrate"):
+        # -- 4. gated migration -----------------------------------------------
+        u = jax.random.uniform(k_mig, (bv,))
+        migrate = wants & (u < p_mig[action])
+        new_lbl = jnp.where(migrate, action, cur)
 
-    # -- 8. exact load update (visible to the next chunk) --------------------
-    dmig = ctx.deg * migrate
-    loads = loads.at[cur].add(-dmig).at[action].add(dmig)
+        # -- 8. exact load update (visible to the next chunk) ----------------
+        dmig = ctx.deg * migrate
+        loads = loads.at[cur].add(-dmig).at[action].add(dmig)
 
     # -- 5. eq. (13) weight accumulation --------------------------------------
     # Each neighbor u of v contributes

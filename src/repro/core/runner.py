@@ -883,9 +883,12 @@ def _run_partitioner_traced(
         # locality-permuted) index space; the load metric uses the full
         # padded arrays because real vertices are not a prefix under a
         # permuted assignment (padding carries zero degree, so the value is
-        # unchanged on contiguous layouts)
-        pending_le.append(local_edges(s.labels, dg.dir_src, dg.dir_dst))
-        pending_ml.append(max_normalized_load(s.labels, dg.deg_out, k))
+        # unchanged on contiguous layouts). Dispatch allocates the metrics'
+        # device buffers, which can wait for the superstep to free its own:
+        # the span shows that wait.
+        with tracer.span("dispatch", what="metrics"):
+            pending_le.append(local_edges(s.labels, dg.dir_src, dg.dir_dst))
+            pending_ml.append(max_normalized_load(s.labels, dg.deg_out, k))
         if tracer.enabled:
             step_ts.append(tracer.now_us())
 

@@ -9,14 +9,19 @@ adding host syncs. This module records exactly those:
 
   * **Spans** — nested wall-clock regions (`Tracer.span`) emitted as
     Chrome/perfetto trace-event JSON (`Tracer.save` -> load the file at
-    https://ui.perfetto.dev). Spans opened *inside* jitted code via
-    `annotate` fire once per trace (XLA compiles the region; Python runs it
-    only at trace time) — they are tagged ``during="trace"`` and nest under
-    the superstep span that triggered the compile, giving the phase
+    https://ui.perfetto.dev). An enabled tracer also writes every span into
+    the profiler's trace as a `jax.profiler.TraceAnnotation` labelled
+    ``name`` (``name:what`` when a ``what`` arg is given), so a device
+    profile captured with `jax.profiler.trace` shows the device's work on
+    the same clock as the spans that waited for it.
+  * **Phase scopes** — `annotate` opens a `jax.named_scope`, whatever the
+    current tracer, so every operation XLA compiles inside it carries the
+    phase name in its ``op_name`` metadata and in device profiles. Inside
+    jitted code the Python block runs only while XLA traces it; an enabled
+    tracer also records it as a span tagged ``during="trace"``, nested
+    under the superstep that triggered the compile, giving the phase
     structure (edge-phase / la-update / halo-exchange) of every compiled
-    superstep variant. `annotate` also opens a `jax.named_scope` (and a
-    `jax.profiler.TraceAnnotation` when available) so the same names line
-    up inside an XLA device profile captured with `jax.profiler.trace`.
+    superstep variant.
   * **Counters** — per-superstep series (`Tracer.counter`) emitted as
     trace-event counter tracks and retained in `Tracer.series` for reports
     and bench artifacts.
@@ -29,8 +34,9 @@ adding host syncs. This module records exactly those:
     diffing the static shape args against the region's previous compile.
 
 Overhead contract (pinned by tests/test_obs.py): the default `NULL_TRACER`
-leaves every instrumented path bit-identical and adds no work — `span` /
-`annotate` return a shared no-op context manager and every recording method
+leaves every instrumented path bit-identical and adds no work — `span`
+returns a shared no-op context manager, `annotate` only the named scope
+(compile-time metadata: labels are unchanged), and every recording method
 is a pass. An enabled tracer adds per-superstep host timestamps, one O(n)
 device comparison for the migration counter, and counter drains that ride
 the *existing* `sync_every` windows — never an additional device sync.
@@ -43,6 +49,8 @@ import os
 import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
+
+import jax
 
 _NULL_CTX = contextlib.nullcontext()
 
@@ -60,7 +68,7 @@ class NullTracer:
         return _NULL_CTX
 
     def annotate(self, name: str, **args):
-        return _NULL_CTX
+        return jax.named_scope(name)
 
     def instant(self, name: str, **args) -> None:
         pass
@@ -92,16 +100,11 @@ class Tracer:
     stream, a CLI invocation with several algorithms); pass it via
     ``run_partitioner(trace=...)`` / ``StreamRunner(trace=...)`` /
     ``launch partition --trace PATH`` and call `save(path)` at the end.
-
-    `xprof=True` (default) additionally opens `jax.named_scope` +
-    `jax.profiler.TraceAnnotation` inside `annotate`, so span names appear
-    in XLA metadata and in device profiles captured with
-    `jax.profiler.trace` — alignment is free when you are not profiling.
     """
 
     enabled = True
 
-    def __init__(self, *, xprof: bool = True):
+    def __init__(self):
         self.events: List[Dict[str, Any]] = []
         # counter name -> [(step, value)]; step is None for run-level gauges
         self.series: Dict[str, List[Tuple[Optional[int], float]]] = {}
@@ -111,12 +114,9 @@ class Tracer:
         self._t0 = time.perf_counter_ns()
         self._pending_causes: List[str] = []
         self._last_compile_args: Dict[str, Dict[str, Any]] = {}
-        self._xprof = xprof
-        if xprof:
-            import jax
-
-            self._named_scope = jax.named_scope
-            self._trace_annotation = jax.profiler.TraceAnnotation
+        # a subclass that overrides `span` writes its own profiler
+        # annotation (or none): bridging here too would write each span twice
+        self._bridge = type(self).span is Tracer.span
 
     # ------------------------------------------------------------------ #
     # clocks / event plumbing
@@ -133,34 +133,38 @@ class Tracer:
     # spans
     # ------------------------------------------------------------------ #
     @contextlib.contextmanager
-    def span(self, name: str, **args):
-        """Record a complete ("X") span around the enclosed block."""
+    def _record(self, name: str, args: Dict[str, Any]):
         ts = self.now_us()
         try:
             yield self
         finally:
             self._emit({"ph": "X", "name": name, "ts": ts,
-                        "dur": self.now_us() - ts,
-                        "args": args or {}})
+                        "dur": self.now_us() - ts, "args": args})
+
+    @contextlib.contextmanager
+    def span(self, name: str, **args):
+        """Record a complete ("X") span around the enclosed block, and write
+        it into the profiler's trace as ``name`` (``name:what``)."""
+        if not self._bridge:
+            with self._record(name, args):
+                yield self
+            return
+        label = f"{name}:{args['what']}" if "what" in args else name
+        with jax.profiler.TraceAnnotation(label), self._record(name, args):
+            yield self
 
     @contextlib.contextmanager
     def annotate(self, name: str, **args):
-        """Span for code that may run under `jax.jit`.
+        """Phase scope for code that may run under `jax.jit`.
 
-        Inside jitted code the Python block executes only while XLA traces
-        it, so the recorded wall-clock is *trace* time (tagged
-        ``during="trace"``) — one span per compiled variant, nested under
-        the superstep that triggered the compile. The `named_scope` /
-        `TraceAnnotation` side makes the same name show up inside XLA
-        profiles, where the *device* time of the region lives.
+        The `jax.named_scope` puts ``name`` into the ``op_name`` of every
+        operation compiled inside it, where the phase's device time lives.
+        The recorded span is *trace* time (tagged ``during="trace"``): one
+        per compiled variant, nested under the superstep that triggered
+        the compile.
         """
-        args = dict(args, during="trace")
-        if not self._xprof:
-            with self.span(name, **args):
-                yield self
-            return
-        with self._named_scope(name), self._trace_annotation(name), \
-                self.span(name, **args):
+        with jax.named_scope(name), \
+                self._record(name, dict(args, during="trace")):
             yield self
 
     def instant(self, name: str, **args) -> None:

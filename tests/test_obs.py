@@ -12,12 +12,14 @@ The contract pinned here (see docs/observability.md):
 """
 from __future__ import annotations
 
+import contextlib
 import importlib.util
 import json
 import logging
 import os
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -115,6 +117,75 @@ def test_annotate_tags_trace_time():
     assert len(ev) == 1 and ev[0]["args"]["during"] == "trace"
 
 
+@pytest.fixture
+def annotations(monkeypatch):
+    """Labels of the profiler annotations opened, in order."""
+    opened = []
+
+    class Recorder:
+        def __init__(self, label):
+            opened.append(label)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    return opened
+
+
+def test_spans_reach_the_profiler(annotations):
+    t = obs.Tracer()
+    with t.span("superstep", step=0):
+        with t.span("device-sync", steps=1, what="metrics"):
+            pass
+    with obs.use(t), obs.annotate("edge-phase", impl="jnp"):
+        pass
+    # trace-time phase spans stay host-side: their device time is in the
+    # named scope, not in an annotation
+    assert annotations == ["superstep", "device-sync:metrics"]
+    assert [e["name"] for e in t.events] == ["device-sync", "superstep",
+                                            "edge-phase"]
+
+
+def test_null_tracer_opens_no_annotation(annotations):
+    with obs.NULL_TRACER.span("superstep", step=0), obs.annotate("edge-phase"):
+        pass
+    assert annotations == []
+
+
+def test_subclass_span_is_not_written_twice(annotations):
+    """A subclass that writes its own annotation around `span` gets one
+    annotation per span, not two."""
+
+    class Own(obs.Tracer):
+        @contextlib.contextmanager
+        def span(self, name, **args):
+            with jax.profiler.TraceAnnotation("own:" + name), \
+                    super().span(name, **args):
+                yield self
+
+    with Own().span("superstep"):
+        pass
+    assert annotations == ["own:superstep"]
+
+
+@pytest.mark.parametrize("tracer", [None, "on"])
+def test_annotate_names_the_compiled_ops(tracer):
+    """The phase scope reaches the compiled program whether or not a tracer
+    is installed."""
+
+    def f(x):
+        with obs.annotate("edge-phase"):
+            return jnp.sin(x) * 2
+
+    with obs.use(obs.Tracer() if tracer else None):
+        text = jax.jit(f).lower(jnp.ones(4)).compile().as_text()
+    assert "/edge-phase/" in text
+
+
 # --------------------------------------------------------------------------
 # traced batch runs
 # --------------------------------------------------------------------------
@@ -147,6 +218,18 @@ def test_traced_run_records_spans_and_counters(graph):
     summary = t.summary()
     assert summary["spans"]["superstep"]["count"] == res.steps
     json.dumps(summary)   # artifact-embeddable
+
+
+def test_traced_run_spans_the_metric_dispatch(graph):
+    t = obs.Tracer()
+    res = run_partitioner("revolver", graph, 4, seed=1, max_steps=3,
+                          patience=10_000, trace=t)
+    spans = [e for e in t.events if e["ph"] == "X"]
+    dispatch = [e for e in spans if e["name"] == "dispatch"]
+    assert [e["args"] for e in dispatch] == [{"what": "metrics"}] * res.steps
+    # each between its superstep's dispatch and the window's drain
+    sup = [e for e in spans if e["name"] == "superstep"]
+    assert all(s["ts"] + s["dur"] <= d["ts"] for s, d in zip(sup, dispatch))
 
 
 def test_tracing_off_is_bit_identical(graph):
