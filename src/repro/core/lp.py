@@ -5,12 +5,34 @@ Both scorers share one primitive — the *edge label histogram*: for every
 vertex v accumulate, per partition l, the eq.-(4)-weighted count of neighbors
 currently labeled l. `edge_histogram_jnp` is the XLA scatter-add reference;
 `repro.kernels.edge_histogram` is the Pallas TPU kernel (one-hot matmul on
-the MXU) with identical semantics.
+the MXU) with identical semantics. `gather_pair` is how the edge phase
+reads two label-valued vertex vectors at the same edge indices.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+# `gather_pair` packs two values into the halves of one int32 word; the high
+# half keeps the sign bit clear, so each value has 15 bits and label-valued
+# pairs need k <= MAX_PAIR_K
+MAX_PAIR_K = (1 << 15) - 1
+
+
+def gather_pair(a: jax.Array, b: jax.Array,
+                idx: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """``(a[idx], b[idx])`` through one indexed read.
+
+    A TPU gather costs per index, not per byte, so two int32 vectors read
+    at the same indices are packed into one word (``a | b << 16``),
+    gathered once and unpacked with a mask and a shift. Both must be int32
+    with values in ``[0, MAX_PAIR_K]``. The TPU compiler materialises the
+    packed word as the gather's operand (``tests/test_tpu_compile.py``
+    counts the superstep's gathers); fused into the gather, the packing
+    would read ``a`` and ``b`` at every index again.
+    """
+    word = (a | (b << 16))[idx]
+    return word & 0xFFFF, word >> 16
 
 
 def edge_histogram_jnp(

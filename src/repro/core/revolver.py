@@ -38,7 +38,8 @@ from repro import obs
 from repro.core import engine
 from repro.core.device_graph import CAPACITY_MODES, DeviceGraph, ShardedDeviceGraph  # noqa: F401  (re-exported API)
 from repro.core.la import split_weights_and_signals, weighted_la_update
-from repro.core.lp import edge_histogram_jnp, revolver_scores
+from repro.core.lp import (MAX_PAIR_K, edge_histogram_jnp, gather_pair,
+                           revolver_scores)
 from repro.core.registry import register
 
 # valid values per config knob; typos used to silently fall back to the jnp
@@ -110,6 +111,11 @@ class RevolverConfig:
             raise ValueError(
                 "staleness_bound > 0 only applies to chunk_schedule='async' "
                 f"(got chunk_schedule={self.chunk_schedule!r})")
+        if self.k > MAX_PAIR_K:
+            # the edge phase reads label pairs packed into one int32 word
+            raise ValueError(
+                f"RevolverConfig.k={self.k} exceeds {MAX_PAIR_K}, the most "
+                "the edge phase's packed label pairs hold")
 
 
 class RevolverState(NamedTuple):
@@ -257,8 +263,9 @@ def _revolver_chunk_rule(cfg: RevolverConfig, ctx: engine.ChunkContext,
     # kernels/edge_phase.py; for weight_mode="self_lambda" the kernel
     # returns the per-row (A, N) factorization and the lambda(v) one-hot
     # scatter is finished below once scores exist). The jnp path is the
-    # two-scatter-add reference with identical semantics.
-    with obs.annotate("edge-phase", impl=cfg.hist_impl):
+    # two-scatter-add reference with identical semantics. Vertex values read
+    # at the same edge indices go through one packed gather (`gather_pair`).
+    with obs.annotate("edge-phase", impl=cfg.hist_impl, gather="packed"):
         if fused_op is not None:
             feasible_f = (p_mig > 0).astype(jnp.float32)
             hist, w_acc = fused_op(
@@ -267,7 +274,8 @@ def _revolver_chunk_rule(cfg: RevolverConfig, ctx: engine.ChunkContext,
                 block_v=bv, k=k, weight_mode=cfg.weight_mode)
             hist, w_acc = hist[0], w_acc[0]
         else:
-            nbr_labels = labels[ctx.e_dst]           # async: freshest labels
+            # async: freshest labels; lam_nbr feeds eq. 13 below
+            nbr_labels, lam_nbr = gather_pair(labels, lam, ctx.e_dst)
             hist = edge_histogram_jnp(ctx.e_row, nbr_labels, ctx.e_w, bv, k)
             w_acc = None
 
@@ -296,7 +304,8 @@ def _revolver_chunk_rule(cfg: RevolverConfig, ctx: engine.ChunkContext,
     # The slot written depends on cfg.weight_mode (eq. 13 ambiguity):
     #   self_lambda     -> slot lambda(v) (the literal LHS w(v, lambda(v)))
     #   neighbor_lambda -> slot lambda(u)
-    with obs.annotate("edge-phase", impl=cfg.hist_impl, part="weights"):
+    with obs.annotate("edge-phase", impl=cfg.hist_impl, gather="packed",
+                      part="weights"):
         if w_acc is not None:
             if cfg.weight_mode == "self_lambda":
                 # finish the kernel's (A, N) packing: every edge of row v
@@ -308,12 +317,11 @@ def _revolver_chunk_rule(cfg: RevolverConfig, ctx: engine.ChunkContext,
             else:
                 w_raw = w_acc                        # finished in-kernel
         else:
-            lam_nbr = lam[ctx.e_dst]
-            agree = (action[ctx.e_row] == lam_nbr)
             if cfg.weight_mode == "self_lambda":
-                slot = lam_chunk[ctx.e_row]
+                row_action, slot = gather_pair(action, lam_chunk, ctx.e_row)
             else:
-                slot = lam_nbr
+                row_action, slot = action[ctx.e_row], lam_nbr
+            agree = (row_action == lam_nbr)
             feasible = p_mig[slot] > 0
             val = jnp.where(agree, ctx.e_w, jnp.where(feasible, 1.0, 0.0))
             val = jnp.where(ctx.e_w > 0, val, 0.0)  # kill padding slots

@@ -54,6 +54,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.lp import gather_pair
+
 _WEIGHT_MODES = ("self_lambda", "neighbor_lambda")
 
 # block_v * edge_chunk elements of the per-cell row indicator, ~16 B each
@@ -96,8 +98,7 @@ def _edge_slot_values(edge_dst, edge_rows, edge_vals, labels, lam, actions,
     """Per-edge (slot, value) pairs of both histograms — the gathers the
     kernel leaves to XLA. Returns ``(score_slot, score_val, w_slot,
     w_val)``, each ``[nb, e_max]``."""
-    nbr_lbl = labels[edge_dst]
-    lam_nbr = lam[edge_dst]
+    nbr_lbl, lam_nbr = gather_pair(labels, lam, edge_dst)
     live = (edge_vals > 0).astype(jnp.float32)           # padding kill
     agree = jnp.take_along_axis(actions, edge_rows, axis=1) == lam_nbr
     if weight_mode == "neighbor_lambda":

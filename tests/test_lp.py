@@ -5,7 +5,9 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core.lp import (
+    MAX_PAIR_K,
     edge_histogram_jnp,
+    gather_pair,
     normalized_penalty,
     revolver_scores,
     spinner_scores,
@@ -38,6 +40,36 @@ class TestEdgeHistogram:
         vals = jnp.array([1.0, 0.0, 2.0])
         out = edge_histogram_jnp(rows, slots, vals, 2, 2)
         np.testing.assert_allclose(np.asarray(out), [[0, 1], [2, 0]])
+
+
+class TestGatherPair:
+    @pytest.mark.parametrize("k", [2, 8, 127, 256, MAX_PAIR_K])
+    @settings(max_examples=10, deadline=None)
+    @given(seed=st.integers(0, 2**16), n=st.integers(1, 300),
+           e=st.integers(1, 500))
+    def test_equals_two_gathers(self, k, seed, n, e):
+        """Both halves come back as the plain gathers read them, for label
+        values up to k - 1 and with padding indices (0) among the reads."""
+        rng = np.random.default_rng(seed)
+        a = rng.integers(0, k, size=n, dtype=np.int32)
+        b = rng.integers(0, k, size=n, dtype=np.int32)
+        a[0], b[-1] = k - 1, k - 1                 # the largest value is read
+        idx = rng.integers(0, n, size=e, dtype=np.int32)
+        idx[rng.random(e) < 0.3] = 0               # padding slots read row 0
+        got_a, got_b = gather_pair(jnp.asarray(a), jnp.asarray(b),
+                                   jnp.asarray(idx))
+        assert got_a.dtype == got_b.dtype == jnp.int32
+        np.testing.assert_array_equal(np.asarray(got_a), a[idx])
+        np.testing.assert_array_equal(np.asarray(got_b), b[idx])
+
+    def test_block_shaped_indices(self):
+        """[nb, e_max] indices (the Pallas wrapper's slabs) keep their shape."""
+        a = jnp.arange(6, dtype=jnp.int32)
+        b = 5 - a
+        idx = jnp.array([[0, 5, 2], [3, 0, 0]], jnp.int32)
+        got_a, got_b = gather_pair(a, b, idx)
+        np.testing.assert_array_equal(np.asarray(got_a), np.asarray(a[idx]))
+        np.testing.assert_array_equal(np.asarray(got_b), np.asarray(b[idx]))
 
 
 class TestNormalizedPenalty:

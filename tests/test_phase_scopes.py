@@ -12,6 +12,7 @@ import sys
 import textwrap
 
 import jax
+import jax.numpy as jnp
 import pytest
 
 from repro import obs
@@ -78,6 +79,49 @@ def test_every_rule_op_sits_in_a_phase_scope(superstep):
             engine_ops += 1
     assert all(seen.values()), seen
     assert engine_ops > 0
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, sub-jaxprs included, depth first."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for p in eqn.params.values():
+            inner = getattr(p, "jaxpr", p)
+            if hasattr(inner, "eqns"):
+                yield from _eqns(inner)
+
+
+@pytest.mark.parametrize("weight_mode", ["self_lambda", "neighbor_lambda"])
+def test_edge_phase_reads_vertex_values_in_two_gathers(weight_mode):
+    """Per block, the jnp rule makes two per-edge gathers from vertex
+    vectors: the packed neighbour (label, lambda) word from the `[n_pad]`
+    view, and the row's packed (action, lambda) word (`self_lambda`) or
+    its action (`neighbor_lambda`) from a `[block_v]` vector. The `[k]`
+    lookup `p_mig[slot]` is not one of them. The gathers and the packing
+    sit in the edge phase."""
+    g = dc_sbm(256, 2048, n_comm=4, mixing=0.25, degree_exponent=0.5, seed=5)
+    dg = prepare_device_graph(g, n_blocks=4)
+    cfg = RevolverConfig(k=4, weight_mode=weight_mode)
+    state = revolver_init(dg, cfg, jax.random.PRNGKey(0))
+    body = _block_scan(jax.make_jaxpr(
+        lambda s: revolver_superstep(dg, cfg, s))(state).jaxpr)
+    assert len({dg.n_pad, dg.block_v, dg.e_max, cfg.k}) == 4
+    vertex_gathers, packing = [], []
+    for eqn in _eqns(body):
+        name = eqn.primitive.name
+        if name == "gather":
+            operand, idx = (v.aval for v in eqn.invars[:2])
+            if operand.shape in ((dg.n_pad,), (dg.block_v,)) and \
+                    idx.size == dg.e_max:
+                vertex_gathers.append((operand.shape, eqn))
+        elif name in ("shift_left", "or") and \
+                eqn.outvars[0].aval.dtype == jnp.int32:   # not the PRNG's
+            packing.append(eqn)
+    assert sorted(shape for shape, _ in vertex_gathers) == \
+        [(dg.block_v,), (dg.n_pad,)]
+    assert len(packing) == (2 if weight_mode == "self_lambda" else 1) * 2
+    for eqn in packing + [eqn for _, eqn in vertex_gathers]:
+        assert "edge-phase" in str(eqn.source_info.name_stack).split("/")
 
 
 _COMPILE = textwrap.dedent("""

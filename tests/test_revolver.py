@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from repro.core.device_graph import capacity, capacity_device, prepare_device_graph
+from repro.core.lp import MAX_PAIR_K
 from repro.core.metrics import local_edges, max_normalized_load, partition_loads
 from repro.core.revolver import RevolverConfig, revolver_init, revolver_superstep
 from repro.core.runner import run_partitioner
@@ -152,6 +153,12 @@ class TestConfigValidation:
     def test_revolver_bad_choice_raises(self, field, bad):
         with pytest.raises(ValueError, match=field):
             RevolverConfig(k=4, **{field: bad})
+
+    def test_revolver_k_beyond_packed_pairs_raises(self):
+        """The edge phase packs label pairs into 16-bit halves of one word."""
+        assert RevolverConfig(k=MAX_PAIR_K).k == MAX_PAIR_K
+        with pytest.raises(ValueError, match="k=32768"):
+            RevolverConfig(k=MAX_PAIR_K + 1)
 
     def test_revolver_valid_choices_accepted(self):
         cfg = RevolverConfig(k=4, la_impl="pallas", hist_impl="pallas",

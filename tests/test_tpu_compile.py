@@ -5,7 +5,9 @@ accepts layouts the TPU compiler refuses (unaligned tiles, in-kernel
 gathers, dynamic slices, VMEM overflows). Here they are compiled for one
 chip of a described -- not attached -- ``v5e:2x2`` topology, at the shapes
 of `chip_smoke.py`'s kernel leg: the paper's WIKI graph at scale 1.0 cut
-into 2048-vertex blocks. Nothing runs; a refusal fails the test.
+into 2048-vertex blocks. Nothing runs; a refusal fails the test. The jnp
+superstep is compiled too, at a 64th of `usa-k8`'s block shapes, to count
+the gathers the TPU compiler makes of its edge phase.
 
 The topology is described inside a module fixture, never at import time:
 only one process at a time may load the TPU library, and every test worker
@@ -13,11 +15,15 @@ imports this file. The persistent compilation cache is off around the
 compiles (a described-topology compile cannot be read back from it).
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.core.device_graph import DeviceGraph
+from repro.core.revolver import (RevolverConfig, RevolverState,
+                                 revolver_superstep)
 from repro.kernels import ops
 from repro.kernels.edge_phase import (MAX_INDICATOR_ELEMS,
                                       fused_edge_phase_pallas)
@@ -75,6 +81,35 @@ def test_la_update_compiles_for_v5e(one_chip):
         x, x, x, alpha=1.0, beta=0.1, block_v=256,
         interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("weight_mode", ["self_lambda", "neighbor_lambda"])
+def test_superstep_gathers_each_vertex_pair_once_for_v5e(one_chip, weight_mode):
+    """The jnp superstep, compiled for the chip, reads vertex values at the
+    edges through two gathers per block: the TPU compiler keeps each packed
+    pair word (`lp.gather_pair`) as the gather's operand and does not fuse
+    the packing back in, which would read both halves per index again."""
+    nb, block_v, e_max, m = 8, 46768, 114176, 912384   # usa-k8 / 64
+    n_pad = nb * block_v
+    cfg = RevolverConfig(k=K, weight_mode=weight_mode)
+
+    def step(arrays, state):
+        dg = DeviceGraph(n_pad - 8, n_pad, m, nb, block_v, e_max, *arrays)
+        return revolver_superstep(dg, cfg, state)
+
+    i32, f32 = jnp.int32, jnp.float32
+    arrays = tuple(_shape(shape, dtype, one_chip) for shape, dtype in (
+        ((m,), i32), ((m,), i32), ((m,), f32),            # edge_src/dst/w
+        ((m,), i32), ((m,), i32),                         # dir_src/dst
+        ((nb, e_max), i32), ((nb, e_max), i32), ((nb, e_max), f32),
+        ((n_pad,), f32), ((n_pad,), f32), ((n_pad,), jnp.bool_)))
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    state = RevolverState(*(_shape(shape, dtype, one_chip) for shape, dtype in (
+        ((n_pad,), i32), ((n_pad,), i32), ((nb, block_v, K), f32),
+        ((K,), f32), (key.shape, key.dtype), ((), i32), ((), f32))))
+    text = jax.jit(step).lower(arrays, state).compile().as_text()
+    gathers = re.findall(r"= s32\[(\d+)\]\S* gather\(", text)
+    assert gathers == [str(e_max)] * 2
 
 
 def test_edge_phase_refuses_oversized_block_v():
