@@ -13,7 +13,11 @@ each `run_partitioner("revolver", g, k, seed=<run seed>, dg=dg,
 max_steps=S, patience=S)` at the program's defaults otherwise, from init to
 labels on the host in original vertex order. Jobs start while the elapsed
 time is under `--seconds`; the last one finishes. Every job uses the run's
-seed, so each is the same work and has the same answer.
+seed, so each is the same work and has the same answer. A traced run
+(`--trace 1`) hands every job, the warm-up's too, the program's own
+`obs.Tracer`, profiles the window, and reduces the profile by program
+(`tracing`) and by phase scope and host span (`phases`) into the record's
+`trace`.
 
 `correct` compares every job of the window with the plain reference
 (`reference.revolver_labels`, replayed once after the window from the same
@@ -175,17 +179,18 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     """Set-up, window and check of one run; returns the run record."""
     import jax
 
-    from benchlib import graphgen, reference, tracing
+    from benchlib import graphgen, phases, reference, tracing
+    from repro import obs
     from repro.core import prepare_device_graph
 
     cfg, k, steps = cell.config, int(cell.traffic["k"]), int(cell.traffic["supersteps"])
     device = jax.devices()[0]
-    tracer = tracing.profiled_tracer() if trace else None
+    tracer = obs.Tracer() if trace else None
     compiles = CompileCounter.get()
 
     # ---- set-up -----------------------------------------------------------
     t_setup = time.perf_counter()
-    g = graphgen.generate(cfg, seed, log)
+    g = graphgen.generate(cfg, seed, log, root=cell.root)
     gen_s = time.perf_counter() - t_setup
     gen_peak = _peak_bytes(device)
     log(f"graph {cfg['name']} seed={seed}: |V|={g.n} |E|={g.m} "
@@ -246,7 +251,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     reduced = None
     if trace:
         t = time.perf_counter()
-        reduced = tracing.reduce_trace(tracing.extract(trace_dir))
+        raw = phases.extract(trace_dir)
+        reduced = {**tracing.reduce_trace(raw), **phases.reduce_phases(raw)}
         shutil.rmtree(trace_dir, ignore_errors=True)
         log(f"trace reduction {time.perf_counter() - t:.3f} s: "
             f"{json.dumps(reduced)}")

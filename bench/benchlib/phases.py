@@ -1,17 +1,18 @@
 """Device time of a traced run by the program's phase scopes and spans.
 
-The program puts every operation of its superstep rule in one phase scope
+The program puts the operations of its superstep in phase scopes
 (`obs.annotate`, a `jax.named_scope`): `la-select`, `edge-phase`,
-`migrate`, `la-update`. The scope reaches the operation's HLO `op_name`
-metadata, which the TPU profile keeps as the `tf_op` stat of the
-operation's event metadata, e.g.
-`jit(_sequential_superstep)/while/body/closed_call/edge-phase/gather:`.
-`tracing.extract` keeps only the operation's HLO text, and
-`jax.profiler.ProfileData` shows no metadata stats; `extract` here reads
-the profile with `xplane` and returns `tracing.extract`'s record, with the
-program's `dispatch` spans among the host spans and the path and the phase
-of each operation beside it (`op_paths`, `op_phases`), so
-`tracing.reduce_trace` reads it too.
+`migrate`, `la-update` in the Revolver rule, `halo-exchange` and
+`interior-scan` in the sharded and overlapped schedules, which may hold
+others. The scope reaches the operation's HLO `op_name` metadata, which
+the TPU profile keeps as the `tf_op` stat of the operation's event
+metadata, e.g.
+`jit(_sequential_superstep)/while/body/closed_call/edge-phase/gather:`; an
+operation's phase is the innermost scope on that path. `extract` reads the
+profile with `xplane` (`jax.profiler.ProfileData` shows no metadata
+stats) and returns the device's operation and program (module) events and
+the host spans, with the path and the phase of each operation beside it
+(`op_paths`, `op_phases`); `tracing.reduce_trace` reads the same record.
 
 An operation the compiler made has no `op_name` of its own, and the
 profile then names the instruction that holds it (the block scan's
@@ -26,9 +27,9 @@ operation's name.
 recorded traces with no chip:
 
 - `phase_busy_s`: self time of the operations inside the superstep
-  programs by phase, and `unscoped` for the rest (the block scan's
-  `while`, its splices). None where no such operation has a phase: the
-  phases are then unknown, not zero.
+  programs by phase, one key per scope of SCOPES, and `unscoped` for the
+  rest (the block scan's `while`, its splices). None where no such
+  operation has a phase: the phases are then unknown, not zero.
 - `phase_from_hlo_s`: the part of each phase placed through the compiled
   module rather than by the operation's own path.
 - `busy_by_span`: device busy time outside the superstep programs, by the
@@ -44,7 +45,9 @@ from typing import Dict, FrozenSet, List, Optional
 
 from benchlib import tracing, xplane
 
-PHASES = ("la-select", "edge-phase", "migrate", "la-update")
+# every scope the program opens (`obs.annotate` in `src/repro`)
+SCOPES = ("la-select", "edge-phase", "migrate", "la-update", "halo-exchange",
+          "interior-scan")
 UNSCOPED = "unscoped"
 # stats of an operation's event metadata: its HLO op_name and its program,
 # and of a module's on the metadata plane: the compiled module
@@ -55,9 +58,9 @@ HOST_SPANS = tracing.HOST_SPANS + ("dispatch",)
 
 
 def phase_of(path: str) -> str:
-    """The phase that is a component of an op_name path, else UNSCOPED."""
-    for part in path.split("/"):
-        if part in PHASES:
+    """The innermost scope of SCOPES on an op_name path, else UNSCOPED."""
+    for part in reversed(path.split("/")):
+        if part in SCOPES:
             return part
     return UNSCOPED
 
@@ -106,9 +109,11 @@ def instruction_phases(hlo_proto: bytes) -> Dict[str, str]:
 
 
 def extract(log_dir: str, device: str = "/device:TPU:0") -> dict:
-    """`tracing.extract`'s record, plus ``"op_paths"``, the op_name path of
-    each entry of ``"ops"`` ("" where it has none), and ``"op_phases"``,
-    its phase, in the same order."""
+    """Plain record of a profile: ``{"ops": [[name, start_ns, dur_ns],
+    ...], "modules": [...], "host": [...], "op_paths": [...],
+    "op_phases": [...]}``: the device plane's "XLA Ops" and "XLA Modules"
+    lines, the host spans in HOST_SPANS, and the op_name path of each entry
+    of ``"ops"`` ("" where it has none) and its phase, in the same order."""
     paths = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
     if not paths:
         raise RuntimeError(f"no profile written under {log_dir}")
@@ -197,7 +202,7 @@ def reduce_phases(rec: dict) -> dict:
     from_hlo: Dict[str, float] = {}
     if any(ph != UNSCOPED for (ph, _), _, _ in step_ops):
         self_ns = tracing._self_times(step_ops)
-        phase_busy = {p: 0.0 for p in PHASES + (UNSCOPED,)}
+        phase_busy = {p: 0.0 for p in SCOPES + (UNSCOPED,)}
         for (ph, hlo), ns in self_ns.items():
             phase_busy[ph] += ns / 1e9
             if hlo:
@@ -214,3 +219,13 @@ def reduce_phases(rec: dict) -> dict:
         "busy_by_span": {n: v / 1e9 for n, v in
                          sorted(by_span.items(), key=lambda kv: -kv[1])},
     }
+
+
+def phase_ms(rec: dict, scope: str) -> Optional[float]:
+    """Device self time per superstep of `scope` in a run record's trace,
+    in ms; None where the trace has no phases or no time in that scope."""
+    t = rec.get("trace") or {}
+    busy = (t.get("phase_busy_s") or {}).get(scope)
+    if not busy or not t.get("supersteps"):
+        return None
+    return 1e3 * busy / t["supersteps"]

@@ -1,21 +1,20 @@
 """The traced run: a `jax.profiler` trace of the window, and its reduction.
 
-`profiled_tracer` gives the program's `repro.obs.Tracer` with every host span
-also written into the profiler's trace as a `TraceAnnotation`, so the
+The program's own `repro.obs.Tracer` writes each of its spans into the
+profiler's trace as a `TraceAnnotation` (`name`, or `name:what`), so the
 program's spans (`run-partitioner`, `superstep`, `device-sync`, ...) sit on
-the device's clock. `extract` turns the profiler's `.xplane.pb` into a plain
-record: the device's operation and program (module) events, and the host
-annotations. `reduce_trace` works on that record alone, so it is checked
-against a small recorded trace with no chip.
+the device's clock. `phases.extract` turns the profiler's `.xplane.pb` into
+a plain record: the device's operation and program (module) events, and the
+host annotations. `reduce_trace` works on that record alone, so it is
+checked against a small recorded trace with no chip.
 
-On the TPU the operation events carry no named-scope metadata, so device
-time is attributed by program: the program's jitted superstep (a module
-whose name holds "superstep") against everything else the device runs.
+Device time is attributed here by program: the program's jitted superstep
+(a module whose name holds "superstep") against everything else the device
+runs; `phases.reduce_phases` splits both further, by phase scope and by
+host span.
 """
 from __future__ import annotations
 
-import contextlib
-import glob
 import re
 from typing import Dict, List, Optional
 
@@ -27,22 +26,6 @@ HOST_SPANS = ("bench-window", "bench-job", "run-partitioner", "prepare-layout",
 SUPERSTEP_MODULE = "superstep"
 TOP = 10
 _HLO = re.compile(r"^(%\S+) = (\S+) ([a-z][\w-]*)\(")
-
-
-def profiled_tracer():
-    """A program tracer whose spans also land in the profiler's trace."""
-    import jax
-    from repro import obs
-
-    class ProfiledTracer(obs.Tracer):
-        @contextlib.contextmanager
-        def span(self, name, **args):
-            label = f"{name}:{args['what']}" if "what" in args else name
-            with jax.profiler.TraceAnnotation(label), \
-                    super().span(name, **args):
-                yield self
-
-    return ProfiledTracer()
 
 
 def profile_options():
@@ -58,36 +41,6 @@ def op_name(hlo_text: str) -> str:
     """'%fusion.12 s32[7033344]{0} fusion' from an operation's HLO line."""
     m = _HLO.match(hlo_text)
     return " ".join(m.groups()) if m else hlo_text[:80]
-
-
-def extract(log_dir: str, device: str = "/device:TPU:0") -> dict:
-    """Plain record of a profile: ``{"ops": [[name, start_ns, dur_ns],
-    ...], "modules": [...], "host": [...]}`` — the device plane's "XLA Ops"
-    and "XLA Modules" lines and the host annotations in HOST_SPANS."""
-    import jax
-
-    paths = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
-    if not paths:
-        raise RuntimeError(f"no profile written under {log_dir}")
-    pd = jax.profiler.ProfileData.from_file(paths[0])
-    rec: Dict[str, List[list]] = {"ops": [], "modules": [], "host": []}
-    for plane in pd.planes:
-        if plane.name == device:
-            for line in plane.lines:
-                key = {"XLA Ops": "ops", "XLA Modules": "modules"}.get(line.name)
-                if key is None:
-                    continue
-                for ev in line.events:
-                    name = op_name(ev.name) if key == "ops" else ev.name
-                    rec[key].append([name, float(ev.start_ns),
-                                     float(ev.duration_ns)])
-        elif plane.name.startswith("/host:"):
-            for line in plane.lines:
-                for ev in line.events:
-                    if ev.name.split(":")[0] in HOST_SPANS:
-                        rec["host"].append([ev.name, float(ev.start_ns),
-                                            float(ev.duration_ns)])
-    return rec
 
 
 def _union(intervals) -> List[tuple]:

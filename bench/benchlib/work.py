@@ -30,8 +30,16 @@ def _bits(x: int) -> int:
     return max(1, math.ceil(math.log2(x)))
 
 
+def edge_phase_bytes(n: int, ms: int, k: int) -> float:
+    """Least HBM bytes of a superstep's edge phase (steps 3 and 5)."""
+    return ms * (_bits(n) + 1) / 8 + 2 * n * _bits(k) / 8
+
+
+def la_update_bytes(n: int, k: int) -> float:
+    """Least HBM bytes of a superstep's LA update (steps 6-7)."""
+    return 2 * n * k * F32 + 2 * n * _bits(k) / 8
+
+
 def superstep_bytes(n: int, ms: int, k: int) -> float:
     """Least HBM bytes a superstep moves."""
-    edge_phase = ms * (_bits(n) + 1) / 8 + 2 * n * _bits(k) / 8
-    la_update = 2 * n * k * F32 + 2 * n * _bits(k) / 8
-    return edge_phase + la_update
+    return edge_phase_bytes(n, ms, k) + la_update_bytes(n, k)

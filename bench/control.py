@@ -38,7 +38,7 @@ def main(argv=None) -> int:
 
     k, steps = int(cell.traffic["k"]), int(cell.traffic["supersteps"])
     for seed in args.seeds:
-        g = graphgen.generate(cell.config, seed, harness.log)
+        g = graphgen.generate(cell.config, seed, harness.log, root=root)
         dg = prepare_device_graph(g)
         layout = harness.layout_of(dg)
         prog = harness.Job(0.0, 0.0, *harness.program_job(g, dg, k, seed, steps, None))

@@ -3,9 +3,11 @@ known answers; an excerpt of a `usa-k8` trace recorded on a TPU v5e; the
 recorded WIKI excerpt of `test_bench_trace.py`, whose operations carry no
 scope; and the profile's protobufs, written by hand, read as
 `jax.profiler.ProfileData` reads them."""
+import glob
 import gzip
 import json
 import os
+import re
 import sys
 
 import pytest
@@ -13,8 +15,9 @@ import pytest
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.dirname(HERE))
 
-from benchlib import phases, tracing  # noqa: E402
+from benchlib import phases, spec, tracing, work  # noqa: E402
 
+ROOT = os.path.dirname(os.path.dirname(HERE))
 MS = 1e6  # ns
 BODY = "jit(_sequential_superstep)/while/body/closed_call"
 
@@ -97,9 +100,22 @@ def test_no_scope_reads_as_unknown():
     ("jit(f)/la-update/jit(clip)/max", "la-update"),
     (BODY + "/edge-phases/add", "unscoped"),
     ("", "unscoped"),
+    # nested scopes: the innermost decides
+    ("jit(s)/interior-scan/while/body/edge-phase/gather", "edge-phase"),
+    ("jit(s)/interior-scan/halo-exchange/all-gather", "halo-exchange"),
+    ("jit(s)/interior-scan/while/add", "interior-scan"),
 ])
 def test_phase_is_a_path_component(path, phase):
     assert phases.phase_of(path) == phase
+
+
+def test_scopes_are_every_scope_the_program_opens():
+    opened = set()
+    for path in glob.glob(os.path.join(ROOT, "src", "repro", "**", "*.py"),
+                          recursive=True):
+        with open(path) as f:
+            opened |= set(re.findall(r'annotate\(\s*"([^"]+)"', f.read()))
+    assert opened and opened == set(phases.SCOPES)
 
 
 def test_interval_difference():
@@ -140,6 +156,7 @@ def test_recorded_usa_trace_splits_the_superstep():
     assert got == pytest.approx({
         "la-select": 0.216169357, "edge-phase": 3.800699503,
         "migrate": 0.420185289, "la-update": 0.07611863,
+        "halo-exchange": 0.0, "interior-scan": 0.0,
         "unscoped": 0.059328764}, rel=1e-8)
     # the four phases and the block scan's own operations make the
     # superstep; the scan's own are a small remainder
@@ -171,6 +188,63 @@ def test_recorded_usa_trace_places_the_metric_drain():
     metrics = spans["device-sync:metrics"] + spans["dispatch:metrics"]
     assert 0.99 * t["outside_superstep_busy_s"] < metrics <= \
         t["outside_superstep_busy_s"]
+
+
+PHASE_METRICS = ("la_select_ms", "edge_phase_ms", "edge_phase_roofline",
+                 "migrate_ms", "la_update_ms", "step_metrics_ms")
+
+
+def _usa_record(trace):
+    """A `usa-k8` run record (23,941,449 vertices, 58,391,992 symmetrized
+    slots, k=8) around a reduced trace."""
+    return {"n": 23941449, "ms": 58391992, "k": 8,
+            "device_kind": "TPU v5 lite", "trace": trace}
+
+
+@pytest.mark.parametrize("name", PHASE_METRICS)
+def test_phase_metrics_read_the_recorded_usa_trace(name):
+    """Each phase metric's reader gives, from the run record, the number
+    `reduce_phases` gives for the excerpt's one superstep."""
+    rec = _usa_excerpt()
+    r = phases.reduce_phases(rec)
+    busy, spans = r["phase_busy_s"], r["busy_by_span"]
+    edge_s = busy["edge-phase"]
+    want = {
+        "la_select_ms": 1e3 * busy["la-select"],
+        "edge_phase_ms": 1e3 * edge_s,
+        "edge_phase_roofline": 100 * work.edge_phase_bytes(23941449, 58391992, 8)
+        / 819e9 / edge_s,
+        "migrate_ms": 1e3 * busy["migrate"],
+        "la_update_ms": 1e3 * busy["la-update"],
+        "step_metrics_ms": 1e3 * (spans["dispatch:metrics"]
+                                  + spans["device-sync:metrics"]),
+    }[name]
+    trace = {**tracing.reduce_trace(rec), **r}
+    got = spec.metric_reader(ROOT, name)(_usa_record(trace))
+    assert got == pytest.approx(want, rel=1e-12)
+    assert got > 0
+    if name == "edge_phase_roofline":
+        assert got < 100
+
+
+@pytest.mark.parametrize("name", PHASE_METRICS)
+def test_phase_metrics_read_nothing_without_phases(name):
+    """A record whose trace was reduced by program alone has no phases to
+    read: they are unknown, not zero."""
+    rec = _usa_record(tracing.reduce_trace(_usa_excerpt()))
+    assert spec.metric_reader(ROOT, name)(rec) is None
+
+
+def test_the_phases_and_the_scan_make_the_superstep_metric():
+    """The four phase metrics and the unscoped remainder add up to
+    `superstep_ms` on the recorded superstep."""
+    rec = _usa_excerpt()
+    r = phases.reduce_phases(rec)
+    record = _usa_record({**tracing.reduce_trace(rec), **r})
+    parts = sum(spec.metric_reader(ROOT, n)(record) for n in (
+        "la_select_ms", "edge_phase_ms", "migrate_ms", "la_update_ms"))
+    assert parts + 1e3 * r["phase_busy_s"]["unscoped"] == pytest.approx(
+        spec.metric_reader(ROOT, "superstep_ms")(record), rel=1e-9)
 
 
 # ---- the profile's protobufs, written by hand --------------------------------
@@ -250,10 +324,33 @@ def test_instruction_phases_from_a_compiled_module():
                    "loop.9": "edge-phase", "add.10": "migrate"}
 
 
+def _profiledata_record(log_dir, device="/device:TPU:0"):
+    """The device's operations and modules and the host spans of a profile,
+    as `jax.profiler.ProfileData` reads them."""
+    import jax
+
+    pd = jax.profiler.ProfileData.from_file(
+        next(log_dir.glob("*.xplane.pb")).as_posix())
+    rec = {"ops": [], "modules": [], "host": []}
+    for plane in pd.planes:
+        for line in plane.lines:
+            key = {"XLA Ops": "ops", "XLA Modules": "modules"}.get(line.name)
+            for ev in line.events:
+                name = tracing.op_name(ev.name) if key == "ops" else ev.name
+                if plane.name == device and key is not None:
+                    rec[key].append([name, float(ev.start_ns),
+                                     float(ev.duration_ns)])
+                elif (plane.name.startswith("/host:")
+                      and ev.name.split(":")[0] in phases.HOST_SPANS):
+                    rec["host"].append([ev.name, float(ev.start_ns),
+                                        float(ev.duration_ns)])
+    return rec
+
+
 def test_extract_reads_what_profiledata_reads(tmp_path):
     """`phases.extract` on a hand-written profile: the record
-    `tracing.extract` reads through `jax.profiler.ProfileData`, plus each
-    operation's path and phase."""
+    `jax.profiler.ProfileData` reads, plus each operation's path and
+    phase."""
     step = "jit__sequential_superstep(77)"
     body = "jit(_sequential_superstep)/while/body/closed_call"
     hlo = _hlo((1, [(1, "gather.1", body + "/edge-phase/gather", [], []),
@@ -285,9 +382,9 @@ def test_extract_reads_what_profiledata_reads(tmp_path):
         (1, device), (1, metadata), (1, host)))
 
     rec = phases.extract(str(tmp_path))
-    plain = tracing.extract(str(tmp_path))
+    plain = _profiledata_record(tmp_path)
     assert rec["ops"] == plain["ops"] and rec["modules"] == plain["modules"]
-    assert [h for h in rec["host"] if h[0] != "dispatch:metrics"] == plain["host"]
+    assert rec["host"] == plain["host"]
     assert rec["ops"][0] == ["%gather.1 s32[8]{0} gather", 1000.0, 2500.0]
     assert rec["host"] == [["bench-window", 500.0, 20_000_000.0],
                            ["superstep", 900.0, 100.0],

@@ -4,6 +4,7 @@ bfloat16 in the program's place) and each fault the cell can have, planted
 in the timed path, come out not correct. And the command itself refuses a
 CPU backend."""
 import dataclasses
+import hashlib
 import json
 import os
 import shutil
@@ -60,8 +61,10 @@ def restore_jax_config():
     compilation_cache.reset_cache()
 
 
-def _run(root, capsys, job_fn=harness.program_job):
-    assert harness.main(ARGS, root=root, chip=False, job_fn=job_fn) == 0
+def _run(root, capsys, job_fn=harness.program_job, workload="toy-k8"):
+    args = list(ARGS)
+    args[args.index("--workload") + 1] = workload
+    assert harness.main(args, root=root, chip=False, job_fn=job_fn) == 0
     out, err = capsys.readouterr()
     result = json.loads(out.strip().splitlines()[-1])
     # the compared numbers close standard error, each beside its limit
@@ -110,6 +113,60 @@ def test_reference_follows_a_permuted_layout(toy_root):
     contiguous = reference.revolver_labels(g, k, 11, steps, dg.n_blocks,
                                            dg.block_v)
     assert np.mean(contiguous != ref) > 0.1
+
+
+RING = '''"""A ring: every vertex joined both ways to the next."""
+import numpy as np
+
+from benchlib import graphgen
+from repro.graphs.csr import build_graph
+
+
+def generate(cfg, relabel_key, log=None):
+    n = int(cfg["n"])
+    perm = np.asarray(graphgen.relabeling(relabel_key, n))
+    v = np.arange(n)
+    return build_graph(perm[v], perm[(v + 1) % n], n)
+'''
+
+
+def _tree_digests(root):
+    return {os.path.relpath(os.path.join(d, f), root):
+            hashlib.sha256(open(os.path.join(d, f), "rb").read()).hexdigest()
+            for d, _, files in os.walk(os.path.join(root, "bench"))
+            for f in files}
+
+
+def test_new_graph_family_runs_from_its_file_alone(tmp_path, capsys,
+                                                   restore_jax_config):
+    """A family that is one new file, with a configuration, limits and the
+    entries that name them, runs a cell end to end to `correct`; no file
+    of the benchmark's tree that was there changes."""
+    root = str(tmp_path)
+    shutil.copytree(os.path.join(ROOT, "bench"), os.path.join(root, "bench"),
+                    ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    before = _tree_digests(root)
+    bdir = os.path.join(root, "bench")
+    with open(os.path.join(bdir, "families", "ring.py"), "w") as f:
+        f.write(RING)
+    with open(os.path.join(bdir, "configs", "toy-ring.json"), "w") as f:
+        json.dump({"name": "toy-ring", "family": "ring", "n": 2048}, f)
+    shutil.copy(os.path.join(bdir, "limits", "usa-k8.json"),
+                os.path.join(bdir, "limits", "ring-k8.json"))
+    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    usa = {w["name"]: w for w in bench["workloads"]}["usa-k8"]
+    bench["configs"].append({"name": "toy-ring", "source": "x", "why": "x",
+                             "file": "bench/configs/toy-ring.json",
+                             "reduced": []})
+    bench["workloads"].append(dict(usa, name="ring-k8", config="toy-ring"))
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+
+    r = _run(root, capsys, workload="ring-k8")
+    assert r["correct"] is True and r["failed"] == 0 and r["attempted"] >= 1
+    assert r["checks"]["labels_differ"]["value"] == 0.0
+    after = _tree_digests(root)
+    assert {k: after[k] for k in before} == before
 
 
 def _unchanged_state(monkeypatch):

@@ -79,6 +79,7 @@ def test_new_cell_config_traffic_and_metric_are_found_by_name(tmp_path):
         root, [m for m in cell.per_layer if m["name"] in ("jobs_run", "layout_s")],
         {"jobs": [{}, {}], "setup": {"layout_s": 2.0}})
     assert got["jobs_run"] == {"value": 2.0, "unit": "jobs"}
+    assert got["layout_s"] == {"value": 2.0, "unit": "s"}
     # a metric whose reader finds nothing to read is left out of the line
     assert spec.read_metrics(
         root, [m for m in cell.per_layer if m["name"] == "superstep_ms"],
@@ -86,3 +87,28 @@ def test_new_cell_config_traffic_and_metric_are_found_by_name(tmp_path):
     # nothing that was there changed
     after = _digests(root)
     assert {k: after[k] for k in before} == before
+
+
+def test_every_per_layer_metric_is_read_in_every_cell_that_reports_what_it_moves(
+        tmp_path):
+    root = str(tmp_path)
+    shutil.copytree(os.path.join(ROOT, "bench"), os.path.join(root, "bench"),
+                    ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    shutil.copy(os.path.join(ROOT, "bench", "limits", "usa-k8.json"),
+                os.path.join(root, "bench", "limits", "other-k8.json"))
+    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    usa = {w["name"]: w for w in bench["workloads"]}["usa-k8"]
+    bench["workloads"].append(dict(usa, name="other-k8"))
+    bench["per_layer"].append({"name": "unreported", "unit": "s",
+                               "better": "lower", "source": "host_clock",
+                               "layer": "entry", "moves": "no_such_metric"})
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+
+    def names(metrics):
+        return [m["name"] for m in metrics]
+
+    for name in ("usa-k8", "other-k8"):
+        cell = spec.load_cell(root, name)
+        assert names(cell.end_to_end) == names(bench["end_to_end"])
+        assert names(cell.per_layer) == names(bench["per_layer"])[:-1]

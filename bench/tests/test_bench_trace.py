@@ -3,6 +3,7 @@ of a trace recorded on a TPU v5e (a 1.79M-vertex, 28.5M-edge graph at k=8,
 one job's first superstep with its metric drain)."""
 import gzip
 import json
+import math
 import os
 import sys
 
@@ -11,7 +12,7 @@ import pytest
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.dirname(HERE))
 
-from benchlib import spec, tracing  # noqa: E402
+from benchlib import spec, tracing, work  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(HERE))
 MS = 1e6  # ns
@@ -110,3 +111,19 @@ def test_readers_return_nothing_without_a_device_trace():
                      "outside_superstep_busy_s": 0.0, "supersteps": 3}}
     for name in ("superstep_ms", "superstep_roofline", "loop_device_ms"):
         assert spec.metric_reader(ROOT, name)(rec) is None
+
+
+@pytest.mark.parametrize("k", [2, 8, 64, 127, 256])
+def test_superstep_bytes_are_its_phases(k):
+    """The edge phase's and the LA update's least bytes, each as the
+    superstep's count has it, add up to the superstep's to the same float."""
+    def bits(x):
+        return max(1, math.ceil(math.log2(x)))
+
+    for n in (1, 7, 1794474, 23941449, 2**22, 2**31 - 1):
+        for ms in (0, 3, 56202030, 58391992, 127 * 2**20):
+            edge = ms * (bits(n) + 1) / 8 + 2 * n * bits(k) / 8
+            la = 2 * n * k * 4 + 2 * n * bits(k) / 8
+            assert work.edge_phase_bytes(n, ms, k) == edge
+            assert work.la_update_bytes(n, k) == la
+            assert work.superstep_bytes(n, ms, k) == edge + la
