@@ -50,11 +50,12 @@ schedules.
 
 Load-delta accounting lives here too: rules mutate their drifting ``loads``
 view freely; the engine recovers the shard's superstep delta as
-``loads_end - loads_start`` (exact — loads are sums of integer-valued
-degrees in f32) and psum-merges it at the superstep boundary. The
-sequential path simply keeps ``loads_end``, so sequential rules no longer
-carry sharded-only accumulator slots (the dead ``delta`` chain the PR-3
-scan threaded through every chunk is gone).
+``loads_end - loads_start`` (exact while every part's load is below 2^24
+= 16,777,216: loads are sums of integer-valued degrees in f32, and f32
+holds every integer only up to there) and psum-merges it at the superstep
+boundary. The sequential path simply keeps ``loads_end``, so sequential
+rules no longer carry sharded-only accumulator slots (the dead ``delta``
+chain the PR-3 scan threaded through every chunk is gone).
 """
 from __future__ import annotations
 
