@@ -32,6 +32,7 @@ from repro.core.halo import (
 from repro.graphs.blocking import (
     block_adjacency,
     block_edges,
+    block_row_ptr,
     locality_block_order,
     vcycle_block_order,
 )
@@ -62,6 +63,10 @@ class DeviceGraph(NamedTuple):
     deg_out: jnp.ndarray      # [n_pad] f32 outdegree (load contribution)
     inv_wsum: jnp.ndarray     # [n_pad] f32 1/sum_u w_hat(u,v) (0 if isolated)
     vmask: jnp.ndarray        # [n_pad] bool real-vertex mask
+    # [n_blocks, block_v + 1] int32 row pointers into each slab, for the
+    # edge phase's prefix-sum histograms; None where they cannot pay or be
+    # exact (`graphs.blocking.block_row_ptr`) and on sharded layouts
+    blk_ptr: Optional[jnp.ndarray] = None
 
 
 def prepare_device_graph(g: Graph, n_blocks: int = 8, block_multiple: int = 8) -> DeviceGraph:
@@ -72,6 +77,7 @@ def prepare_device_graph(g: Graph, n_blocks: int = 8, block_multiple: int = 8) -
     blocked = block_edges(g, block_v=block_v)
     n_blocks = blocked.n_blocks
     n_pad = blocked.n_pad
+    blk_ptr = block_row_ptr(g, blocked.block_v, n_blocks, blocked.e_max)
 
     deg_out = np.zeros(n_pad, dtype=np.float32)
     deg_out[: g.n] = g.deg_out.astype(np.float32)
@@ -104,6 +110,7 @@ def prepare_device_graph(g: Graph, n_blocks: int = 8, block_multiple: int = 8) -
         deg_out=jnp.asarray(deg_out),
         inv_wsum=jnp.asarray(inv_wsum),
         vmask=jnp.asarray(vmask),
+        blk_ptr=None if blk_ptr is None else jnp.asarray(blk_ptr),
     )
 
 
@@ -201,6 +208,7 @@ def align_blocks(dg: DeviceGraph, multiple: int) -> DeviceGraph:
         blk_dst=pad_rows(dg.blk_dst, 0),
         blk_row=pad_rows(dg.blk_row, 0),
         blk_w=pad_rows(dg.blk_w, 0.0),
+        blk_ptr=None if dg.blk_ptr is None else pad_rows(dg.blk_ptr, 0),
         deg_out=jnp.pad(dg.deg_out, (0, pad_v)),
         inv_wsum=jnp.pad(dg.inv_wsum, (0, pad_v)),
         vmask=jnp.pad(dg.vmask, (0, pad_v)),
@@ -265,6 +273,8 @@ def permute_blocks(dg: DeviceGraph, perm: np.ndarray) -> DeviceGraph:
         blk_dst=jnp.asarray(o2s[np.asarray(dg.blk_dst)[perm]]),
         blk_row=jnp.asarray(np.asarray(dg.blk_row)[perm]),
         blk_w=jnp.asarray(np.asarray(dg.blk_w)[perm]),
+        blk_ptr=(None if dg.blk_ptr is None
+                 else jnp.asarray(np.asarray(dg.blk_ptr)[perm])),
         deg_out=per_vertex(dg.deg_out),
         inv_wsum=per_vertex(dg.inv_wsum),
         vmask=per_vertex(dg.vmask),
@@ -349,7 +359,8 @@ def shard_device_graph(
     if "blocks" not in mesh.axis_names:
         raise ValueError(f"mesh {mesh.axis_names} has no 'blocks' axis")
     n_shards = int(mesh.shape["blocks"])
-    dg = align_blocks(dg, n_shards)
+    # the sharded schedules build their histograms by scatter-add
+    dg = align_blocks(dg._replace(blk_ptr=None), n_shards)
     adj = None
     if isinstance(assignment, str) and assignment in ("locality", "vcycle"):
         # computed once here, seeded onto the returned layout's cache so

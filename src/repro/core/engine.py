@@ -125,6 +125,9 @@ class Algorithm:
         (enables ``keep_probs`` / ``init_probs`` / ``init_sharpen`` in the
         runner and probability carrying in the streaming path).
       chunk_rule / shard_rule: the local rule (exactly one, per ``kind``).
+      run_counters: ``(cfg, dg) -> {name: value}`` static per-run gauges an
+        enabled tracer records once per ``run_partitioner`` call (e.g.
+        which edge-phase path the layout selects), or None.
     """
 
     name: str
@@ -141,6 +144,7 @@ class Algorithm:
     supports_probs: bool = False
     chunk_rule: Optional[Callable] = None
     shard_rule: Optional[Callable] = None
+    run_counters: Optional[Callable] = None
 
     def __post_init__(self):
         if self.kind not in ("chunk", "shard"):
@@ -204,6 +208,9 @@ class ChunkContext(NamedTuple):
                             # every shard drifts from; == the drifting loads
                             # arg at the first chunk of a sequential scan)
     repl: Dict[str, jnp.ndarray]
+    # [block_v + 1] int32 row pointers into the slab (rows stored in order),
+    # or None: only the sequential schedule passes the layout's `blk_ptr`
+    e_ptr: Optional[jnp.ndarray] = None
 
     def shared_headroom(self, cap, loads) -> jnp.ndarray:
         """Per-partition capacity this block may spend without cross-shard
@@ -519,17 +526,18 @@ def _chunk_superstep(algo, cfg, layout, axis, graph, cap, state, step):
         graph["deg"].reshape(bps, block_v),
         graph["inv_wsum"].reshape(bps, block_v),
         graph["vmask"].reshape(bps, block_v),
+        graph.get("blk_ptr"),
     )
 
     def scan_step(carry, x):
         vert, loads, key, score_sum = carry
-        blk_idx, e_dst, e_row, e_w, block, deg, inv_wsum, vmask = x
+        blk_idx, e_dst, e_row, e_w, block, deg, inv_wsum, vmask, e_ptr = x
         gv0 = blk_idx * block_v
         v0 = (blk_idx - idx * bps) * block_v if halo else gv0
         ctx = ChunkContext(
             blk_idx=blk_idx, v0=v0, gv0=gv0, e_dst=e_dst, e_row=e_row,
             e_w=e_w, deg=deg, inv_wsum=inv_wsum, vmask=vmask, step=step,
-            n_shards=n_shards, loads0=loads0, repl=repl)
+            n_shards=n_shards, loads0=loads0, repl=repl, e_ptr=e_ptr)
         upd = algo.chunk_rule(cfg, ctx, vert, block, loads, cap, key)
         vert = {f: jax.lax.dynamic_update_slice(vert[f], upd.vert[f], (ctx.v0,))
                 for f in vert}
@@ -881,6 +889,9 @@ def superstep(algo: Algorithm, dg, cfg, state, halo=None):
         dg = dg.dg
     layout = _Layout(dg.n, dg.n_pad, dg.n_blocks, dg.block_v, dg.n_blocks)
     graph = _graph_arrays(dg)
+    if dg.blk_ptr is not None:
+        # rows in slab order: chunk rules may build segment sums from them
+        graph["blk_ptr"] = dg.blk_ptr
     if halo is not None and halo.hub_owner is not None and not halo.fallback:
         if halo.n_shards != 1:
             raise ValueError(

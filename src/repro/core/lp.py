@@ -5,8 +5,10 @@ Both scorers share one primitive — the *edge label histogram*: for every
 vertex v accumulate, per partition l, the eq.-(4)-weighted count of neighbors
 currently labeled l. `edge_histogram_jnp` is the XLA scatter-add reference;
 `repro.kernels.edge_histogram` is the Pallas TPU kernel (one-hot matmul on
-the MXU) with identical semantics. `gather_pair` is how the edge phase
-reads two label-valued vertex vectors at the same edge indices.
+the MXU) with identical semantics; `sorted_segment_histogram` computes it
+from int32 prefix sums where the slab's rows are stored in order and
+eq.-(4) weights are integers. `gather_pair` is how the edge phase reads
+two label-valued vertex vectors at the same edge indices.
 """
 from __future__ import annotations
 
@@ -52,6 +54,49 @@ def edge_histogram_jnp(
     """
     hist = jnp.zeros((n_rows, k), dtype=vals.dtype)
     return hist.at[rows, slots].add(vals)
+
+
+def prefix_histogram_pays(n_rows: int, e_max: int, k: int) -> bool:
+    """Whether a slab of `e_max` slots over `n_rows` sorted rows builds its
+    histograms from prefix sums (`sorted_segment_histogram`): the k score
+    bins and the eq.-13 pair cost ``k + 2`` row-end reads per row, which
+    beat the scatter-adds' sorted passes over every slot only while a row
+    averages at least that many slots."""
+    return e_max >= (k + 2) * n_rows
+
+
+def sorted_segment_sum(ptr: jax.Array, vals: jax.Array) -> jax.Array:
+    """Per-row sums of int32 `vals` over a slab whose rows are stored in
+    order: row r holds slots ``[ptr[r], ptr[r+1])``.
+
+    The prefix sum read at each row's end, less the one at the previous
+    row's end: no sort and no scatter, one indexed read per row. An empty
+    row reads 0; slots past ``ptr[-1]`` (padding) are never read.
+    """
+    csum = jnp.cumsum(vals)
+    at = jnp.where(ptr > 0, csum[jnp.maximum(ptr - 1, 0)], 0)
+    return at[1:] - at[:-1]
+
+
+def sorted_segment_histogram(ptr: jax.Array, slots: jax.Array,
+                             vals: jax.Array, k: int) -> jax.Array:
+    """`edge_histogram_jnp` for a slab whose rows are stored in order, in
+    int32: ``hist[r, c]`` sums the int32 `vals` of row r's slots with
+    ``slots == c``.
+
+    One bin at a time (a `fori_loop`), so a single ``[e_max]`` prefix sum
+    is live. Integer sums are exact in any order: while the slab's total
+    stays below 2^31 and each row's below 2^24, the f32 of the result
+    equals the f32 scatter-add's bit for bit.
+    """
+    n_rows = ptr.shape[0] - 1
+
+    def one_bin(c, acc):
+        col = sorted_segment_sum(ptr, jnp.where(slots == c, vals, 0))
+        return jax.lax.dynamic_update_index_in_dim(acc, col, c, 0)
+
+    acc = jax.lax.fori_loop(0, k, one_bin, jnp.zeros((k, n_rows), jnp.int32))
+    return acc.T
 
 
 def tau_term(hist: jax.Array, inv_wsum: jax.Array) -> jax.Array:

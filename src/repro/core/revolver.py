@@ -39,7 +39,8 @@ from repro.core import engine
 from repro.core.device_graph import CAPACITY_MODES, DeviceGraph, ShardedDeviceGraph  # noqa: F401  (re-exported API)
 from repro.core.la import split_weights_and_signals, weighted_la_update
 from repro.core.lp import (MAX_PAIR_K, edge_histogram_jnp, gather_pair,
-                           revolver_scores)
+                           prefix_histogram_pays, revolver_scores,
+                           sorted_segment_histogram, sorted_segment_sum)
 from repro.core.registry import register
 
 # valid values per config knob; typos used to silently fall back to the jnp
@@ -263,9 +264,18 @@ def _revolver_chunk_rule(cfg: RevolverConfig, ctx: engine.ChunkContext,
     # kernels/edge_phase.py; for weight_mode="self_lambda" the kernel
     # returns the per-row (A, N) factorization and the lambda(v) one-hot
     # scatter is finished below once scores exist). The jnp path is the
-    # two-scatter-add reference with identical semantics. Vertex values read
-    # at the same edge indices go through one packed gather (`gather_pair`).
-    with obs.annotate("edge-phase", impl=cfg.hist_impl, gather="packed"):
+    # two-scatter-add reference with identical semantics; where the layout
+    # passes the slab's row pointers and rows are long enough to pay for
+    # them, it builds the same histograms from int32 prefix sums read at
+    # the row ends (`lp.sorted_segment_histogram`; exact, since eq.-4
+    # weights are integers). Vertex values read at the same edge indices
+    # go through one packed gather (`gather_pair`).
+    prefix = (fused_op is None and ctx.e_ptr is not None
+              and prefix_histogram_pays(bv, ctx.e_w.shape[0], k))
+    hist_path = ("fused" if fused_op is not None
+                 else "prefix" if prefix else "scatter")
+    with obs.annotate("edge-phase", impl=cfg.hist_impl, gather="packed",
+                      hist=hist_path):
         if fused_op is not None:
             feasible_f = (p_mig > 0).astype(jnp.float32)
             hist, w_acc = fused_op(
@@ -276,7 +286,13 @@ def _revolver_chunk_rule(cfg: RevolverConfig, ctx: engine.ChunkContext,
         else:
             # async: freshest labels; lam_nbr feeds eq. 13 below
             nbr_labels, lam_nbr = gather_pair(labels, lam, ctx.e_dst)
-            hist = edge_histogram_jnp(ctx.e_row, nbr_labels, ctx.e_w, bv, k)
+            if prefix:
+                e_wi = ctx.e_w.astype(jnp.int32)
+                hist = sorted_segment_histogram(
+                    ctx.e_ptr, nbr_labels, e_wi, k).astype(jnp.float32)
+            else:
+                hist = edge_histogram_jnp(ctx.e_row, nbr_labels, ctx.e_w,
+                                          bv, k)
             w_acc = None
 
         scores = revolver_scores(hist, ctx.inv_wsum, loads, cap)
@@ -305,7 +321,7 @@ def _revolver_chunk_rule(cfg: RevolverConfig, ctx: engine.ChunkContext,
     #   self_lambda     -> slot lambda(v) (the literal LHS w(v, lambda(v)))
     #   neighbor_lambda -> slot lambda(u)
     with obs.annotate("edge-phase", impl=cfg.hist_impl, gather="packed",
-                      part="weights"):
+                      hist=hist_path, part="weights"):
         if w_acc is not None:
             if cfg.weight_mode == "self_lambda":
                 # finish the kernel's (A, N) packing: every edge of row v
@@ -316,6 +332,16 @@ def _revolver_chunk_rule(cfg: RevolverConfig, ctx: engine.ChunkContext,
                     lam_chunk, k, dtype=jnp.float32) * contrib[:, None]
             else:
                 w_raw = w_acc                        # finished in-kernel
+        elif prefix and cfg.weight_mode == "self_lambda":
+            # the (A, N) factorization of the fused kernel, from two prefix
+            # sums: A sums agreeing weights, N counts the other real slots
+            agree = action[ctx.e_row] == lam_nbr
+            # (padding slots lie past the last row end and are never read)
+            a = sorted_segment_sum(ctx.e_ptr, jnp.where(agree, e_wi, 0))
+            n = sorted_segment_sum(ctx.e_ptr, (~agree).astype(jnp.int32))
+            contrib = a + jnp.where(p_mig[lam_chunk] > 0, n, 0)
+            w_raw = jax.nn.one_hot(lam_chunk, k, dtype=jnp.float32) * \
+                contrib.astype(jnp.float32)[:, None]
         else:
             if cfg.weight_mode == "self_lambda":
                 row_action, slot = gather_pair(action, lam_chunk, ctx.e_row)
@@ -323,9 +349,15 @@ def _revolver_chunk_rule(cfg: RevolverConfig, ctx: engine.ChunkContext,
                 row_action, slot = action[ctx.e_row], lam_nbr
             agree = (row_action == lam_nbr)
             feasible = p_mig[slot] > 0
-            val = jnp.where(agree, ctx.e_w, jnp.where(feasible, 1.0, 0.0))
-            val = jnp.where(ctx.e_w > 0, val, 0.0)  # kill padding slots
-            w_raw = edge_histogram_jnp(ctx.e_row, slot, val, bv, k)
+            if prefix:
+                val = jnp.where(agree, e_wi, feasible.astype(jnp.int32))
+                w_raw = sorted_segment_histogram(
+                    ctx.e_ptr, slot, val, k).astype(jnp.float32)
+            else:
+                val = jnp.where(agree, ctx.e_w,
+                                jnp.where(feasible, 1.0, 0.0))
+                val = jnp.where(ctx.e_w > 0, val, 0.0)  # kill padding slots
+                w_raw = edge_histogram_jnp(ctx.e_row, slot, val, bv, k)
 
     # -- 6./7. reinforcement signals + weighted LA update ---------------------
     with obs.annotate("la-update", impl=cfg.la_impl):
@@ -346,6 +378,16 @@ def _revolver_chunk_rule(cfg: RevolverConfig, ctx: engine.ChunkContext,
     )
 
 
+def _revolver_run_counters(cfg: RevolverConfig, dg) -> dict:
+    """`edge_hist_prefix`: 1 where the rule builds its histograms from the
+    layout's row pointers (the sequential jnp edge phase on a layout that
+    has them, at rows long enough to pay), else 0."""
+    prefix = (cfg.hist_impl == "jnp" and cfg.chunk_schedule == "sequential"
+              and getattr(dg, "blk_ptr", None) is not None
+              and prefix_histogram_pays(dg.block_v, dg.e_max, cfg.k))
+    return {"edge_hist_prefix": float(prefix)}
+
+
 REVOLVER = register(engine.Algorithm(
     name="revolver",
     config_cls=RevolverConfig,
@@ -359,6 +401,7 @@ REVOLVER = register(engine.Algorithm(
     init_from_labels=revolver_init_from_labels,
     supports_probs=True,
     chunk_rule=_revolver_chunk_rule,
+    run_counters=_revolver_run_counters,
 ))
 
 

@@ -762,6 +762,9 @@ def _run_partitioner_traced(
         )
 
     cfg = _make_cfg(algorithm.config_cls, k, max_steps, cfg_kwargs)
+    if tracer.enabled and algorithm.run_counters is not None:
+        for name, value in algorithm.run_counters(cfg, dg).items():
+            tracer.counter(name, value)
     if not algorithm.supports_probs:
         if init_probs is not None:
             raise TypeError(

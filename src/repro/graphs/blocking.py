@@ -11,6 +11,9 @@ For each edge slot we precompute:
   * `edge_w`    — eq. (4) weight; 0.0 marks padding (padding rows point at
                    local row 0 but carry zero weight, so they are harmless).
 
+Rows are written in order, padding last, so `block_row_ptr` gives each
+slab's row pointers for the edge phase's prefix-sum histograms.
+
 Power-law hubs make per-vertex padding (ELL) explode; per-*block* slabs only
 pad to the worst block, which for RMAT graphs is a small constant factor.
 """
@@ -95,6 +98,30 @@ def fill_block_slab(
     edge_row[out_blk, cnt:] = 0
     edge_w[out_blk, cnt:] = 0.0
     return cnt
+
+
+def block_row_ptr(g: Graph, block_v: int, n_blocks: int,
+                  e_max: int) -> np.ndarray | None:
+    """Per-block row pointers of the slabs `fill_block_slab` writes, for
+    the edge phase's prefix-sum histograms (`repro.core.lp`), or None.
+
+    Row r of block b holds slab slots ``[ptr[b, r], ptr[b, r+1])``
+    (`[n_blocks, block_v + 1]` int32; rows past ``n`` are empty). None
+    where no `k` could use them: a slab averaging under 3 slots a row
+    (`lp.prefix_histogram_pays`), or eq.-(4) weights that are not
+    integers or could carry an int32 prefix sum past 2^31.
+    """
+    if e_max < 3 * block_v:
+        return None
+    w = g.adj_w
+    if w.size and (not np.array_equal(w, np.round(w))
+                   or float(np.abs(w).max()) * e_max >= 2.0 ** 31):
+        return None
+    v = np.minimum(np.arange(n_blocks * block_v + 1, dtype=np.int64), g.n)
+    ptr = np.asarray(g.adj_ptr, dtype=np.int64)[v]
+    rows = np.concatenate([ptr[:-1].reshape(n_blocks, block_v),
+                           ptr[block_v::block_v, None]], axis=1)
+    return (rows - rows[:, :1]).astype(np.int32)
 
 
 def block_edges(g: Graph, block_v: int = 256, edge_chunk: int = 256) -> BlockedEdges:

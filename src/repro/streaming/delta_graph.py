@@ -40,6 +40,7 @@ from repro.core.device_graph import (
 from repro.core.halo import DEFAULT_HALO_THRESHOLD, HubConfig, build_halo_spec
 from repro.graphs.blocking import (
     block_adjacency,
+    block_row_ptr,
     block_slab_sizes,
     fill_block_slab,
     locality_block_order,
@@ -498,6 +499,12 @@ class IncrementalDeviceGraph:
                 return jax.device_put(np.asarray(a), NamedSharding(self.mesh, P()))
         else:
             put_blocked = put_vertex = put_flat = jnp.asarray
+        # row pointers of the slabs as this delta left them, rebuilt from
+        # the merged CSR (natural-order layouts only, as the sequential
+        # schedule reads them)
+        blk_ptr = None
+        if self.mesh is None:
+            blk_ptr = block_row_ptr(g, self.block_v, self.n_blocks, self.e_max)
         return DeviceGraph(
             n=g.n,
             n_pad=n_pad,
@@ -516,4 +523,5 @@ class IncrementalDeviceGraph:
             deg_out=put_vertex(deg_out),
             inv_wsum=put_vertex(inv_wsum),
             vmask=put_vertex(vmask),
+            blk_ptr=None if blk_ptr is None else jnp.asarray(blk_ptr),
         )

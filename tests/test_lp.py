@@ -10,6 +10,8 @@ from repro.core.lp import (
     gather_pair,
     normalized_penalty,
     revolver_scores,
+    sorted_segment_histogram,
+    sorted_segment_sum,
     spinner_scores,
     tau_term,
 )
@@ -40,6 +42,65 @@ class TestEdgeHistogram:
         vals = jnp.array([1.0, 0.0, 2.0])
         out = edge_histogram_jnp(rows, slots, vals, 2, 2)
         np.testing.assert_allclose(np.asarray(out), [[0, 1], [2, 0]])
+
+
+# (row degrees, weights, padded tail, k, form): the slab of
+# `blocking.fill_block_slab`, rows in order and padding (row 0, weight 0)
+# after the last real slot
+_SORTED_SLABS = {
+    "empty-rows": ([0, 3, 0, 0, 5, 1, 0, 0], "eq4", 5, 8, "hist"),
+    "hub": ([2, 900, 1, 0, 3], "eq4", 0, 8, "hist"),
+    "padded-tail": ([1, 4, 0, 2, 6] * 8, "eq4", 300, 2, "hist"),
+    "coarse": ([0, 7, 120, 3, 0, 40, 1] * 3, "coarse", 64, 64, "hist"),
+    "pair-self-lambda": ([0, 6, 1, 0, 400, 2, 9, 0], "eq4", 100, 8, "pair"),
+    "pair-coarse": ([5, 0, 70, 1, 33], "coarse", 7, 64, "pair"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SORTED_SLABS))
+def test_sorted_segment_histogram_equals_scatter(case):
+    """The prefix-sum histogram of a slab whose rows are stored in order
+    equals `edge_histogram_jnp` bit for bit, for eq.-4 weights {1, 2} and
+    coarse integer sums, empty rows (row 0 among them), a hub row, and a
+    padded tail; "pair" is the eq.-13 (A, N) form against the k-slot
+    `self_lambda` scatter."""
+    degrees, weights, pad, k, form = _SORTED_SLABS[case]
+    rng = np.random.default_rng(sum(map(ord, case)))
+    n_rows, real = len(degrees), sum(degrees)
+    ptr = np.concatenate([[0], np.cumsum(degrees)]).astype(np.int32)
+    rows = np.concatenate([np.repeat(np.arange(n_rows), degrees),
+                           np.zeros(pad, np.int64)]).astype(np.int32)
+    hi = 3 if weights == "eq4" else 5000
+    w = rng.integers(1, hi, size=real + pad).astype(np.float32)
+    w[real:] = 0.0
+    slots = rng.integers(0, k, size=real + pad).astype(np.int32)
+    if form == "hist":
+        want = edge_histogram_jnp(jnp.asarray(rows), jnp.asarray(slots),
+                                  jnp.asarray(w), n_rows, k)
+        got = sorted_segment_histogram(
+            jnp.asarray(ptr), jnp.asarray(slots),
+            jnp.asarray(w.astype(np.int32)), k).astype(jnp.float32)
+    else:
+        # slots are the neighbours' lambda; each row has its action, its
+        # lambda and a per-slot feasibility as in revolver's eq. 13
+        action = rng.integers(0, k, size=n_rows).astype(np.int32)
+        lam = rng.integers(0, k, size=n_rows).astype(np.int32)
+        feasible = rng.random(k) < 0.6
+        agree = action[rows] == slots
+        val = np.where(agree, w, np.where(feasible[lam[rows]], 1.0, 0.0))
+        val = np.where(w > 0, val, 0.0).astype(np.float32)
+        want = edge_histogram_jnp(jnp.asarray(rows), jnp.asarray(lam[rows]),
+                                  jnp.asarray(val), n_rows, k)
+        a = sorted_segment_sum(jnp.asarray(ptr),
+                               jnp.asarray(np.where(agree, w, 0).astype(np.int32)))
+        n = sorted_segment_sum(jnp.asarray(ptr),
+                               jnp.asarray((~agree).astype(np.int32)))
+        contrib = np.asarray(a) + np.where(feasible[lam], np.asarray(n), 0)
+        got = (np.eye(k, dtype=np.float32)[lam]
+               * contrib.astype(np.float32)[:, None])
+    assert np.asarray(want).dtype == np.asarray(got).dtype == np.float32
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert np.asarray(want).any()
 
 
 class TestGatherPair:

@@ -97,31 +97,36 @@ def test_edge_phase_reads_vertex_values_in_two_gathers(weight_mode):
     vectors: the packed neighbour (label, lambda) word from the `[n_pad]`
     view, and the row's packed (action, lambda) word (`self_lambda`) or
     its action (`neighbor_lambda`) from a `[block_v]` vector. The `[k]`
-    lookup `p_mig[slot]` is not one of them. The gathers and the packing
-    sit in the edge phase."""
+    lookup `p_mig[slot]` is not one of them. Where the layout's row
+    pointers build the histograms from prefix sums, `self_lambda` reads
+    the row's action alone (its lambda is a per-row value there). The
+    gathers and the packing sit in the edge phase."""
     g = dc_sbm(256, 2048, n_comm=4, mixing=0.25, degree_exponent=0.5, seed=5)
-    dg = prepare_device_graph(g, n_blocks=4)
+    with_ptr = prepare_device_graph(g, n_blocks=4)
+    assert with_ptr.blk_ptr is not None
     cfg = RevolverConfig(k=4, weight_mode=weight_mode)
-    state = revolver_init(dg, cfg, jax.random.PRNGKey(0))
-    body = _block_scan(jax.make_jaxpr(
-        lambda s: revolver_superstep(dg, cfg, s))(state).jaxpr)
-    assert len({dg.n_pad, dg.block_v, dg.e_max, cfg.k}) == 4
-    vertex_gathers, packing = [], []
-    for eqn in _eqns(body):
-        name = eqn.primitive.name
-        if name == "gather":
-            operand, idx = (v.aval for v in eqn.invars[:2])
-            if operand.shape in ((dg.n_pad,), (dg.block_v,)) and \
-                    idx.size == dg.e_max:
-                vertex_gathers.append((operand.shape, eqn))
-        elif name in ("shift_left", "or") and \
-                eqn.outvars[0].aval.dtype == jnp.int32:   # not the PRNG's
-            packing.append(eqn)
-    assert sorted(shape for shape, _ in vertex_gathers) == \
-        [(dg.block_v,), (dg.n_pad,)]
-    assert len(packing) == (2 if weight_mode == "self_lambda" else 1) * 2
-    for eqn in packing + [eqn for _, eqn in vertex_gathers]:
-        assert "edge-phase" in str(eqn.source_info.name_stack).split("/")
+    for dg in (with_ptr, with_ptr._replace(blk_ptr=None)):
+        state = revolver_init(dg, cfg, jax.random.PRNGKey(0))
+        body = _block_scan(jax.make_jaxpr(
+            lambda s: revolver_superstep(dg, cfg, s))(state).jaxpr)
+        assert len({dg.n_pad, dg.block_v, dg.e_max, cfg.k}) == 4
+        vertex_gathers, packing = [], []
+        for eqn in _eqns(body):
+            name = eqn.primitive.name
+            if name == "gather":
+                operand, idx = (v.aval for v in eqn.invars[:2])
+                if operand.shape in ((dg.n_pad,), (dg.block_v,)) and \
+                        idx.size == dg.e_max:
+                    vertex_gathers.append((operand.shape, eqn))
+            elif name in ("shift_left", "or") and \
+                    eqn.outvars[0].aval.dtype == jnp.int32:  # not the PRNG's
+                packing.append(eqn)
+        assert sorted(shape for shape, _ in vertex_gathers) == \
+            [(dg.block_v,), (dg.n_pad,)]
+        row_pair = weight_mode == "self_lambda" and dg.blk_ptr is None
+        assert len(packing) == (2 if row_pair else 1) * 2
+        for eqn in packing + [eqn for _, eqn in vertex_gathers]:
+            assert "edge-phase" in str(eqn.source_info.name_stack).split("/")
 
 
 _COMPILE = textwrap.dedent("""

@@ -6,13 +6,18 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.device_graph import capacity, capacity_device, prepare_device_graph
-from repro.core.lp import MAX_PAIR_K
+from repro import obs
+from repro.core.device_graph import (capacity, capacity_device,
+                                     prepare_device_graph,
+                                     prepare_sharded_device_graph)
+from repro.core.lp import MAX_PAIR_K, prefix_histogram_pays
 from repro.core.metrics import local_edges, max_normalized_load, partition_loads
 from repro.core.revolver import RevolverConfig, revolver_init, revolver_superstep
 from repro.core.runner import run_partitioner
 from repro.core.spinner import SpinnerConfig, spinner_init, spinner_superstep
-from repro.graphs.generators import dc_sbm, ring_of_cliques
+from repro.graphs.blocking import block_row_ptr
+from repro.graphs.generators import dc_sbm, grid_road, ring_of_cliques, rmat
+from repro.streaming import EdgeDelta, IncrementalDeviceGraph, stream_from_graph
 
 
 @pytest.fixture(scope="module")
@@ -216,6 +221,92 @@ class TestFusedHistParity:
                             la_impl="pallas")
         assert 0.0 <= r.local_edges <= 1.0
         assert r.max_norm_load > 0.0
+
+
+def _three_supersteps(dg, cfg, seed=0):
+    state = revolver_init(dg, cfg, jax.random.PRNGKey(seed))
+    for _ in range(3):
+        state = revolver_superstep(dg, cfg, state)
+    return state
+
+
+def _assert_same_state(a, b):
+    for field in ("labels", "lam", "probs", "loads", "score"):
+        np.testing.assert_array_equal(np.asarray(getattr(a, field)),
+                                      np.asarray(getattr(b, field)), field)
+
+
+class TestPrefixHistograms:
+    """Where the layout carries its slabs' row pointers and rows are long
+    enough, the jnp edge phase builds both histograms from int32 prefix
+    sums; the trajectory is the scatter-add's, bit for bit."""
+
+    K = 8
+
+    @pytest.fixture(scope="class")
+    def kron_graph(self):
+        # R-MAT, the Kronecker family: ~20 slots a row at k + 2 = 10
+        return rmat(2048, 24000, seed=3)
+
+    @pytest.mark.parametrize("weight_mode", ["self_lambda", "neighbor_lambda"])
+    def test_prefix_and_scatter_paths_agree(self, kron_graph, weight_mode):
+        dg = prepare_device_graph(kron_graph, n_blocks=4)
+        assert dg.blk_ptr is not None
+        assert prefix_histogram_pays(dg.block_v, dg.e_max, self.K)
+        cfg = RevolverConfig(k=self.K, weight_mode=weight_mode)
+        prefix = _three_supersteps(dg, cfg)
+        scatter = _three_supersteps(dg._replace(blk_ptr=None), cfg)
+        _assert_same_state(prefix, scatter)
+        assert int(np.sum(np.asarray(prefix.labels) != np.asarray(
+            revolver_init(dg, cfg, jax.random.PRNGKey(0)).labels))) > 0
+
+    def test_path_off_on_short_rows(self):
+        """A road lattice with 39% of its segments dropped (`usa-road`'s
+        2.44 slots a row) averages under 3: no pointers, no path."""
+        g = grid_road(4096, seed=0, drop_frac=0.39)
+        dg = prepare_device_graph(g, n_blocks=4)
+        assert dg.e_max < 3 * dg.block_v and dg.blk_ptr is None
+
+    def test_path_off_with_a_non_integer_weight(self, kron_graph):
+        w = kron_graph.adj_w.copy()
+        w[7] = 1.5
+        g = dataclasses.replace(kron_graph, adj_w=w)
+        assert prepare_device_graph(kron_graph, n_blocks=4).blk_ptr is not None
+        assert prepare_device_graph(g, n_blocks=4).blk_ptr is None
+
+    def test_path_off_on_the_sharded_schedule(self, kron_graph):
+        mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("blocks",))
+        sdg = prepare_sharded_device_graph(kron_graph, mesh, n_blocks=4)
+        assert sdg.dg.blk_ptr is None
+        tracer = obs.Tracer()
+        run_partitioner("revolver", kron_graph, self.K, max_steps=1, seed=0,
+                        dg=sdg, chunk_schedule="sharded", trace=tracer)
+        assert [v for _, v in tracer.series["edge_hist_prefix"]] == [0.0]
+
+    def test_run_counter_reads_the_path(self, kron_graph):
+        tracer = obs.Tracer()
+        run_partitioner("revolver", kron_graph, self.K, max_steps=1, seed=0,
+                        n_blocks=4, trace=tracer)
+        assert [v for _, v in tracer.series["edge_hist_prefix"]] == [1.0]
+
+    def test_streaming_slab_rewrite_keeps_pointers_exact(self, kron_graph):
+        """After a delta rewrites a slab in place, the layout's pointers
+        are those of the merged graph and the trajectory is the scatter's."""
+        idg = IncrementalDeviceGraph(kron_graph.n, n_blocks=4)
+        for d in stream_from_graph(kron_graph, 2, seed=1):
+            dg, _ = idg.apply(d)
+        rng = np.random.default_rng(0)
+        dg, info = idg.apply(EdgeDelta.inserts(
+            rng.integers(0, 64, 200).astype(np.int32),
+            rng.integers(0, kron_graph.n, 200).astype(np.int32)))
+        assert not info.repadded and info.dirty_blocks >= 1
+        assert dg.blk_ptr is not None
+        np.testing.assert_array_equal(
+            np.asarray(dg.blk_ptr),
+            block_row_ptr(idg.graph, dg.block_v, dg.n_blocks, dg.e_max))
+        cfg = RevolverConfig(k=self.K)
+        _assert_same_state(_three_supersteps(dg, cfg),
+                           _three_supersteps(dg._replace(blk_ptr=None), cfg))
 
 
 class TestPaperClaims:

@@ -7,7 +7,8 @@ chip of a described -- not attached -- ``v5e:2x2`` topology, at the shapes
 of `chip_smoke.py`'s kernel leg: the paper's WIKI graph at scale 1.0 cut
 into 2048-vertex blocks. Nothing runs; a refusal fails the test. The jnp
 superstep is compiled too, at a 64th of `usa-k8`'s block shapes, to count
-the gathers the TPU compiler makes of its edge phase.
+the gathers the TPU compiler makes of its edge phase, and at
+`rmat-s22-k8`'s, where its histograms come from prefix sums.
 
 The topology is described inside a module fixture, never at import time:
 only one process at a time may load the TPU library, and every test worker
@@ -83,18 +84,16 @@ def test_la_update_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("weight_mode", ["self_lambda", "neighbor_lambda"])
-def test_superstep_gathers_each_vertex_pair_once_for_v5e(one_chip, weight_mode):
-    """The jnp superstep, compiled for the chip, reads vertex values at the
-    edges through two gathers per block: the TPU compiler keeps each packed
-    pair word (`lp.gather_pair`) as the gather's operand and does not fuse
-    the packing back in, which would read both halves per index again."""
-    nb, block_v, e_max, m = 8, 46768, 114176, 912384   # usa-k8 / 64
+def _compiled_superstep(one_chip, nb, block_v, e_max, m, weight_mode,
+                        row_ptr=False):
+    """The jnp superstep at these block shapes, compiled for one v5e chip;
+    `row_ptr` hands it the layout's `blk_ptr`."""
     n_pad = nb * block_v
     cfg = RevolverConfig(k=K, weight_mode=weight_mode)
 
-    def step(arrays, state):
-        dg = DeviceGraph(n_pad - 8, n_pad, m, nb, block_v, e_max, *arrays)
+    def step(arrays, ptr, state):
+        dg = DeviceGraph(n_pad - 8, n_pad, m, nb, block_v, e_max, *arrays,
+                         blk_ptr=ptr)
         return revolver_superstep(dg, cfg, state)
 
     i32, f32 = jnp.int32, jnp.float32
@@ -103,13 +102,48 @@ def test_superstep_gathers_each_vertex_pair_once_for_v5e(one_chip, weight_mode):
         ((m,), i32), ((m,), i32),                         # dir_src/dst
         ((nb, e_max), i32), ((nb, e_max), i32), ((nb, e_max), f32),
         ((n_pad,), f32), ((n_pad,), f32), ((n_pad,), jnp.bool_)))
+    ptr = _shape((nb, block_v + 1), i32, one_chip) if row_ptr else None
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
     state = RevolverState(*(_shape(shape, dtype, one_chip) for shape, dtype in (
         ((n_pad,), i32), ((n_pad,), i32), ((nb, block_v, K), f32),
         ((K,), f32), (key.shape, key.dtype), ((), i32), ((), f32))))
-    text = jax.jit(step).lower(arrays, state).compile().as_text()
+    return jax.jit(step).lower(arrays, ptr, state).compile()
+
+
+@pytest.mark.parametrize("weight_mode", ["self_lambda", "neighbor_lambda"])
+def test_superstep_gathers_each_vertex_pair_once_for_v5e(one_chip, weight_mode):
+    """The jnp superstep, compiled for the chip, reads vertex values at the
+    edges through two gathers per block: the TPU compiler keeps each packed
+    pair word (`lp.gather_pair`) as the gather's operand and does not fuse
+    the packing back in, which would read both halves per index again."""
+    e_max = 114176
+    text = _compiled_superstep(one_chip, 8, 46768, e_max, 912384,  # usa-k8/64
+                               weight_mode).as_text()
     gathers = re.findall(r"= s32\[(\d+)\]\S* gather\(", text)
     assert gathers == [str(e_max)] * 2
+
+
+def test_long_row_histograms_need_no_sort_or_scatter_for_v5e(one_chip):
+    """At `rmat-s22-k8`'s block shapes (31 slots a row), given the row
+    pointers, the superstep builds both [block, k] histograms from prefix
+    sums: none of the sorts over `e_max` slots that the TPU compiler puts
+    in front of the scatter-adds, no scatter but the three k-bin ones,
+    three row-end reads of `block_v + 1` indices beside the two per-slot
+    gathers, and no more temporary memory than the scatter path."""
+    shapes = (8, 524288, 16249088, 128310960)         # rmat-s22-k8
+    e_max, block_v = shapes[2], shapes[1]
+    scatter, prefix = (_compiled_superstep(one_chip, *shapes, "self_lambda",
+                                           row_ptr=p) for p in (False, True))
+    slot_sorts = re.compile(rf"\[{e_max}\]\S*\) sort\(")
+    assert slot_sorts.search(scatter.as_text())
+    text = prefix.as_text()
+    assert not slot_sorts.search(text)
+    scatters = re.findall(r"= (\S+)\{[^}]*\} scatter\(", text)
+    assert scatters == [f"f32[{K}]"] * 3
+    gathers = re.findall(r"= s32\[(\d+)\]\S* gather\(", text)
+    assert sorted(gathers) == sorted([str(e_max)] * 2 + [str(block_v + 1)] * 3)
+    assert prefix.memory_analysis().temp_size_in_bytes <= \
+        scatter.memory_analysis().temp_size_in_bytes
 
 
 def test_edge_phase_refuses_oversized_block_v():
